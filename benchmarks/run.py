@@ -31,6 +31,8 @@ def main(argv=None) -> None:
     ap.add_argument("--json", nargs="?", const="", default=None,
                     help="write rows to a JSON artifact (optional path)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (analysis_check, fig6_neuron_energy, fig9_accuracy,
                             fig9_efficiency, fig11_sparsity_edp,

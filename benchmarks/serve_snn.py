@@ -19,12 +19,6 @@ Three row families:
     latency (queueing + service, in frame ticks scaled by the measured
     tick rate). Lanes never interact, so the gated ``skipped_rows`` /
     ``instr`` values are schedule-independent.
-  * ``serve_snn_mesh_*`` — the serving launcher on a forced-host device
-    mesh (lanes over data, row tiles over model), run in a subprocess
-    because the simulated device count must be set before jax
-    initialises. Entirely report-only (wall-clock scaling on simulated
-    CPU devices carries no perf claim; the *correctness* claim — mesh
-    outputs bit-identical to single-device — is CI's mesh test suite).
 
 Gated keys (tools/bench_gate.py): ``skipped_rows`` (pooled per-slot
 skipped-work fraction; silent (frame, input-row) pairs over all gate
@@ -117,43 +111,6 @@ def _serve_row(program, cfg, sparsity: float, *, n_requests: int,
     return row
 
 
-def _mesh_row(quick: bool) -> str:
-    """Serving over a (2, 2) forced-host mesh via the launcher subprocess.
-
-    The row never fails the gate: when the subprocess cannot run (no
-    XLA CPU multi-device support in this build) it reports
-    ``mesh=unavailable`` instead of a ``*_FAILED`` row — the bit-identity
-    contract is enforced by tests/test_mesh_snn.py, not here."""
-    import os
-    import subprocess
-    import sys
-    repo = pathlib.Path(__file__).resolve().parent.parent
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (str(repo / "src"), env.get("PYTHONPATH")) if p)
-    cmd = [sys.executable, "-m", "repro.launch.serve_snn", "--mesh", "2,2",
-           "--megastep", "4", "--pages", "2"]
-    if quick:
-        cmd.append("--quick")
-    t0 = time.perf_counter()
-    try:
-        out = subprocess.run(cmd, capture_output=True, text=True,
-                             timeout=900, cwd=repo, env=env)
-        dt = time.perf_counter() - t0
-        m = re.search(r"\(([\d.]+) frames/s", out.stdout)
-        if out.returncode != 0 or m is None:
-            raise RuntimeError(out.stderr.strip().splitlines()[-1:]
-                               or "no frames/s in output")
-        derived = (f"frames_per_s={float(m.group(1)):.1f} mesh=2x2 "
-                   f"wall_s={dt:.1f}")
-    except (RuntimeError, subprocess.SubprocessError) as e:
-        dt = time.perf_counter() - t0
-        derived = f"mesh=unavailable wall_s={dt:.1f} ({e})"
-    return emit("serve_snn_mesh_d2m2", dt * 1e6, derived)
-
-
 def _committed_fps(name: str) -> float:
     """frames_per_s of a row in the committed quick baseline, if present —
     the megastep speedup is quoted against the committed ``serve_snn_s85``
@@ -211,8 +168,6 @@ def run(quick: bool = False):
         program, cfg, 0.85, n_requests=n_requests, n_words=n_words,
         slots=slots, pages=2, megastep=8, double_buffer=True,
         poisson_gap=gap, latency=True, key="serve_snn_poisson_s85"))
-    # mesh-sharded serving (subprocess: forced host devices) — report-only
-    rows.append(_mesh_row(quick))
     return rows
 
 

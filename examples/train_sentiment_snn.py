@@ -83,9 +83,8 @@ def main(argv=None):
     # deployed program: compile once, run on the chosen integer backend
     program = pipeline.compile_network(IMDB, params, domain="int")
     xs = pipeline.present_words(x, IMDB.timesteps)
-    bkw = {"interpret": True} if (args.backend == "pallas" and
-                                  (args.interpret or
-                                   jax.default_backend() != "tpu")) else {}
+    bkw = ({"interpret": True}
+           if args.backend == "pallas" and args.interpret else {})
     res = pipeline.run_network(program, xs, args.backend, **bkw)
     logits_i, rasters = res.logits[:, 0], res.rasters
     counts = pipeline.count_network_instructions(program, rasters)

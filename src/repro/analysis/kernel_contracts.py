@@ -27,7 +27,8 @@ the offending call, before any kernel is built:
   gather_bounds       | events-mode index lists are capacity-bounded by
                       | the padded fan-in (index < padded rows of the
                       | VMEM-resident weight tile, by construction of the
-                      | cumsum/one-hot decode — reported with the numbers)
+                      | prefix-sum/one-hot decode — reported with the
+                      | numbers)
   vmem_budget         | the per-`pallas_call` VMEM residency — spike block
                       | across the whole T loop + all weight tiles + all V
                       | scratch/out tiles + rasters + counters — fits the
@@ -188,6 +189,9 @@ def _call_vmem_bytes(widths: tuple, *, n_spiking: int, frames: int,
         n += lanes * 4
     if backend == "pallas_events":
         n += sum(i * 4 for i in ins_p) + LANE * 4    # row counters + fallback
+        # prefix-sum operands: the int8 triangular ones matrix and the int32
+        # position map of the tile, per layer
+        n += sum(i * i + block_b * i * 4 for i in ins_p)
     return n
 
 
@@ -338,7 +342,7 @@ def check_kernel_contracts(program, backend: str = "pallas", *,
             checks.append(ContractCheck(
                 "gather_bounds", name,
                 f"event-list capacity per layer = padded fan-in {caps}; "
-                "cumsum/one-hot indices < capacity by construction"))
+                "prefix-sum/one-hot indices < capacity by construction"))
         vmem = _call_vmem_bytes(
             widths, n_spiking=n_spiking, frames=frames, block_b=block_b,
             backend=backend, gate_granularity=gate_granularity,

@@ -19,7 +19,7 @@ checked:
                    | the dtype rule, so nothing reorder-sensitive remains
   clamp placement  | exactly the contracted number of V-word clamp heads
                    | (``max`` against V_MIN / ``% V_SPAN``, incl. their
-                   | jnp ``pjit`` wrappings) per dispatch; every clamp in
+                   | jnp ``jit`` wrappings) per dispatch; every clamp in
                    | the program's mode; no clamp inside a predicated
                    | (`@pl.when` / `lax.cond`) branch — partials must add
                    | unclamped and the single clamp runs after; every
@@ -31,7 +31,7 @@ checked:
                    | Pallas ``get``/``swap`` row index is provably
                    | in-bounds by interval analysis (event-list gather
                    | indices bounded by the padded fan-in via the
-                   | cumsum/one-hot decode pattern; mesh row-tile starts
+                   | prefix-sum/one-hot decode; mesh row-tile starts
                    | bounded by ``axis_index * rows``)
 
 Violations raise `TraceError` naming the primitive, the eqn's region path
@@ -75,9 +75,8 @@ SURFACES = ("batch", "step", "megastep", "mesh")
 #: abstract mesh extents the mesh surface traces under by default
 DEFAULT_MESH_AXES = (("data", 2), ("model", 2))
 
-_CALL_PRIMS = {"pjit", "closed_call", "core_call", "xla_call", "remat",
-               "remat2", "checkpoint", "custom_jvp_call", "custom_vjp_call",
-               "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr"}
+_CALL_PRIMS = {"jit", "closed_call", "remat2", "custom_jvp_call",
+               "custom_vjp_call"}
 _RNG_PRIMS = {"threefry2x32", "random_seed", "random_bits", "random_wrap",
               "random_unwrap", "random_fold_in", "random_gamma",
               "rng_uniform", "rng_bit_generator"}
@@ -93,7 +92,7 @@ _ELEMENTWISE = {"max", "min", "rem", "add", "sub", "mul", "neg", "sign",
 _PASSTHROUGH = {"convert_element_type", "broadcast_in_dim", "reshape",
                 "squeeze", "expand_dims", "slice", "transpose", "copy",
                 "rev", "reduce_max", "reduce_min", "stop_gradient",
-                "reduce_precision", "abs"}
+                "reduce_precision", "abs", "multiple_of"}
 
 _MAX_DEPTH = 64
 
@@ -348,7 +347,7 @@ def root_region(closed_jaxpr, *, axis_sizes: Optional[dict] = None,
 
 
 # ---------------------------------------------------------------------------
-# const propagation (through pjit boundaries and elementwise chains)
+# const propagation (through jit boundaries and elementwise chains)
 # ---------------------------------------------------------------------------
 
 _CONST_BINOPS = {
@@ -447,7 +446,7 @@ def _bare_clamp_kind(eqn, region) -> Optional[str]:
 def _head_scan(region, kinds: list, depth: int) -> bool:
     """Scan a candidate head body: collect bare clamp patterns, allow
     nested small elementwise calls (``remainder`` wraps a ``_where``
-    pjit), reject anything non-elementwise. True = body is elementwise."""
+    jit), reject anything non-elementwise. True = body is elementwise."""
     if depth > 4 or len(region.jaxpr.eqns) > 16:
         return False
     for e in region.jaxpr.eqns:
@@ -467,7 +466,7 @@ def _head_scan(region, kinds: list, depth: int) -> bool:
 
 def _clamp_kind(eqn, region) -> Optional[str]:
     """Clamp-head kind of an eqn: a bare head, or a small pure-elementwise
-    call (jnp's ``clip``/``remainder`` pjit wrappers, nested calls
+    call (jnp's ``clip``/``remainder`` jit wrappers, nested calls
     allowed) containing exactly one head pattern. A call with control
     flow / dots in its body *contains* clamps but is not itself a head."""
     kind = _bare_clamp_kind(eqn, region)
@@ -549,10 +548,40 @@ def _cmp_interval(p: str, a: Optional[Interval], b: Optional[Interval]
     return Interval(0, 1)
 
 
-def _chain_has_cumsum(atom, region, limit: int = 300) -> bool:
+def _is_triangular_ones(atom, region) -> bool:
+    """True when ``atom`` is an iota-vs-iota comparison (a triangular 0/1
+    matrix), possibly behind dtype converts."""
+    for _ in range(_MAX_DEPTH):
+        if _is_literal(atom):
+            return False
+        if atom in region.bindings and region.parent is not None:
+            atom, region = region.bindings[atom], region.parent
+            continue
+        eqn = region.defs.get(atom)
+        if eqn is None:
+            return False
+        p = eqn.primitive.name
+        if p == "convert_element_type":
+            atom = eqn.invars[0]
+            continue
+        if p not in ("le", "lt", "ge", "gt"):
+            return False
+        dims = []
+        for a in eqn.invars:
+            d = None if _is_literal(a) else region.defs.get(a)
+            if d is None or d.primitive.name not in ("iota",
+                                                     "broadcasted_iota"):
+                return False
+            dims.append(d.params.get("dimension"))
+        return dims[0] != dims[1]
+    return False
+
+
+def _chain_has_prefix_sum(atom, region, limit: int = 300) -> bool:
     """True when the def chain of ``atom`` (crossing call boundaries)
-    contains a cumulative-sum — the structural certificate of the
-    event-list one-hot decode."""
+    contains a prefix sum — a `dot_general` against a triangular ones
+    matrix, the structural certificate of the event-list one-hot
+    decode."""
     stack, seen, steps = [(atom, region)], set(), 0
     while stack and steps < limit:
         a, r = stack.pop()
@@ -569,7 +598,8 @@ def _chain_has_cumsum(atom, region, limit: int = 300) -> bool:
                 stack.append((r.bindings[a], r.parent))
             continue
         p = eqn.primitive.name
-        if p == "cumsum" or "cumsum" in str(eqn.params.get("name", "")):
+        if p == "dot_general" and any(_is_triangular_ones(v, r)
+                                      for v in eqn.invars):
             return True
         subs = _sub_regions(eqn, r) if p in _CALL_PRIMS else ()
         if subs:
@@ -582,13 +612,13 @@ def _chain_has_cumsum(atom, region, limit: int = 300) -> bool:
 
 def _onehot_bound(eqn, region, env, depth) -> Optional[Interval]:
     """Interval of ``reduce_sum(select_n(pred, 0, iota-derived))`` when
-    ``pred``'s chain contains a cumsum comparison — the event-list one-hot
+    ``pred``'s chain contains a prefix-sum comparison — the event-list one-hot
     decode. At most one position matches (the running count of a {0,1}
     raster — the range pass's raster fact — first reaches p+1 exactly
     once), so the sum is bounded by the iota values themselves: the padded
     fan-in, which is the `gather_bounds` kernel contract."""
     op, r, d = eqn.invars[0], region, None
-    for _ in range(_MAX_DEPTH):    # unwrap jnp.where's pjit and bindings
+    for _ in range(_MAX_DEPTH):    # unwrap jnp.where's jit and bindings
         if _is_literal(op):
             return None
         if op in r.bindings and r.parent is not None:
@@ -612,7 +642,7 @@ def _onehot_bound(eqn, region, env, depth) -> Optional[Interval]:
         return None
     pred, case0, case1 = d.invars
     for zero, cand in ((case0, case1), (case1, case0)):
-        if _const_scalar(zero, r) == 0 and _chain_has_cumsum(pred, r):
+        if _const_scalar(zero, r) == 0 and _chain_has_prefix_sum(pred, r):
             return _ival(cand, r, env, depth + 1)
     return None
 
@@ -674,6 +704,13 @@ def _ival_raw(atom, region, env, depth) -> Optional[Interval]:
         if a is None or b is None:
             return None
         return Interval(min(a.lo, b.lo), min(a.hi, b.hi))
+    if p == "div":
+        d = _const_scalar(eqn.invars[1], region)
+        a = op(0)
+        if d is None or isinstance(d, float) or d <= 0 or a is None \
+                or a.lo < 0:
+            return None
+        return Interval(a.lo // int(d), a.hi // int(d))
     if p == "rem":
         d = _const_scalar(eqn.invars[1], region)
         if d is None or d == 0:
@@ -1119,16 +1156,11 @@ def _trace_surfaces(program, backend: str, surfaces: tuple, *, batch: int,
                         thresholds=_t, leaks=_l, neuron=program.neuron,
                         clamp_mode=program.clamp_mode, use_events=_e)
 
-                try:
-                    j = jax.make_jaxpr(
-                        tick, axis_env=list(sizes.items()))(
-                        sds((batch, pw[0]), jnp.int32), wsl_sds, vs_sds)
-                except TypeError:  # axis_env removed in a future jax
-                    j = None
-                if j is not None:
-                    out.append(("mesh", name, j, TraceExpectation(
-                        where=f"{backend}:mesh:{name}",
-                        mesh_axes=tuple(sizes.items()), **expect_kw)))
+                j = jax.make_jaxpr(tick, axis_env=list(sizes.items()))(
+                    sds((batch, pw[0]), jnp.int32), wsl_sds, vs_sds)
+                out.append(("mesh", name, j, TraceExpectation(
+                    where=f"{backend}:mesh:{name}",
+                    mesh_axes=tuple(sizes.items()), **expect_kw)))
     return out
 
 

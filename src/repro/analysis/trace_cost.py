@@ -25,9 +25,11 @@ per additional grid step for *grid-invariant* operands — the 2-D arrays
 3-D operands (the spike frames) are partitioned across the grid. Backends
 with no `pallas_call` (``int_ref``) charge the top-level dispatch
 operands/results once. MACs are *dense* MXU work: `lax.cond` branches
-count as their maximum (the event kernel's gather fallback is bounded by
-its dense branch), a `dot_general` inside an unbounded `while` is
-rejected outright.
+count as their maximum, so the event kernel is charged both its dense
+`@pl.when` and its gather `@pl.when` (whose prefix sum is a block_b x
+n_in x n_in matmul) — an upper bound, since one of the two runs per
+tile and step. A `dot_general` inside an unbounded `while` is rejected
+outright.
 """
 from __future__ import annotations
 

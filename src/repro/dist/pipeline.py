@@ -16,7 +16,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -55,5 +54,5 @@ def make_pipeline_fn(stage_fn: Callable, mesh: Mesh, axis_name: str,
         # only the last rank holds real outputs; psum replicates them
         return jax.lax.psum(jnp.where(idx == n_stages - 1, buf, 0.0), axis_name)
 
-    return shard_map(body, mesh=mesh, in_specs=(P(axis_name), P()),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis_name), P()),
+                         out_specs=P(), check_vma=False)

@@ -70,12 +70,16 @@ gates entirely (an 85%-sparse iid frame runs 15% of its row work here, vs
   the padded n_in lanes IS the fixed-capacity active-row index list —
   entry p (0-based) of the list is the unique lane r with ``pos[r] == p+1``
   and ``frame[r] == 1``, decoded with a one-hot lane match; the list's
-  count is ``pos[-1]`` and its capacity is the padded n_in (so no frame
-  can overflow it). The occupancy-based early-out is the gather loop's
+  count is the frame's event total and its capacity is the padded n_in
+  (so no frame can overflow it). The prefix sum of the whole tile is one
+  exact int8 matmul against the upper-triangular ones matrix (Mosaic
+  lowers no cumsum). The occupancy-based early-out is the gather loop's
   dynamic trip count: a `fori_loop(0, count)` issues exactly ``count``
-  weight-row gathers (`pl.ds` dynamic row loads from the VMEM-resident
-  weight tile) and rank-1 accumulates into the V scratch — an all-silent
-  frame issues zero AccW2V work without any gate test beyond the cumsum.
+  weight-row gathers and rank-1 accumulates into the V scratch — an
+  all-silent frame issues zero AccW2V work. Each gather loads the
+  ``GATHER_ROWS``-row aligned block of the VMEM-resident int8 weight tile
+  that holds the row and selects the row with a one-hot mask: a dynamic
+  int8 row load must start on a packed (32, 128) tile boundary.
 
   Dense fallback (``event_crossover``): gathering beats the MXU only while
   frames are sparse. Per (timestep, layer, batch-tile), when the tile's
@@ -108,6 +112,7 @@ from repro.core.quant import clamp_v, spike_compare
 LANE = 128              # MXU lane tile == the macro's 128-row fan-in
 GATE_GRANULARITIES = (1, 2, 4, 8)
 MAX_SKIP_COLS = 1024    # gate-site columns the skip output will carry
+GATHER_ROWS = 32        # int8 sublane tile: rows per aligned gather load
 
 
 def skip_layout(in_widths: tuple, granularity: int
@@ -248,19 +253,38 @@ def _net_kernel(*refs, n_spiking: int, has_readout: bool, neuron: str,
             fallback_ref[...] = fallback_ref[...] + jnp.where(lane == i, 1, 0)
 
         @pl.when(jnp.logical_not(go_dense))
-        def _gather(i=i, cur32=cur32, n_in_p=n_in_p):
+        def _gather(i=i, cur=cur, cur32=cur32, n_in_p=n_in_p):
             n_out_p = ws[i].shape[1]
             lanes = jax.lax.broadcasted_iota(jnp.int32, (1, n_in_p), 1)
+            # inclusive prefix sum of every row at once, as an exact int8
+            # matmul with the upper-triangular ones matrix (Mosaic has no
+            # cumsum lowering): pos_all[b, k] = sum_{j <= k} cur[b, j]
+            tri = (jax.lax.broadcasted_iota(jnp.int32, (n_in_p, n_in_p), 0)
+                   <= jax.lax.broadcasted_iota(jnp.int32, (n_in_p, n_in_p),
+                                               1)).astype(jnp.int8)
+            pos_all = jax.lax.dot_general(cur, tri, (((1,), (0,)), ((), ())),
+                                          preferred_element_type=jnp.int32)
+            sub_iota = jax.lax.broadcasted_iota(jnp.int32,
+                                                (GATHER_ROWS, n_out_p), 0)
             for b in range(block_b):
                 s = cur32[b:b + 1, :]                    # (1, Nip) 0/1
-                pos_map = jnp.cumsum(s, axis=1)          # the compacted list
-                count = pos_map[0, n_in_p - 1]
+                pos_map = pos_all[b:b + 1, :]            # the compacted list
+                count = jnp.sum(s)
 
                 def ev_body(p, acc, s=s, pos_map=pos_map, lanes=lanes, i=i):
                     hit = (pos_map == p + 1) & (s > 0)   # one-hot lane match
                     idx = jnp.sum(jnp.where(hit, lanes, 0))
-                    row = w_refs[i][pl.ds(idx, 1), :]    # gather one W row
-                    return acc + row.astype(jnp.int32)
+                    # gather one W row: load the aligned int8 row block that
+                    # holds it (single-row dynamic int8 loads must start on
+                    # a packed-tile boundary), then select the row
+                    base = pl.multiple_of(
+                        jax.lax.div(idx, GATHER_ROWS) * GATHER_ROWS,
+                        GATHER_ROWS)
+                    blk = w_refs[i][pl.ds(base, GATHER_ROWS), :]
+                    row = jnp.sum(jnp.where(sub_iota == idx - base,
+                                            blk.astype(jnp.int32), 0),
+                                  axis=0, keepdims=True)
+                    return acc + row
 
                 acc_b = jax.lax.fori_loop(
                     0, count, ev_body,
@@ -335,9 +359,7 @@ def _net_kernel(*refs, n_spiking: int, has_readout: bool, neuron: str,
             if sparse or events:
                 cur = mask_pad(cur, logical_widths[i + 1])
             if emit_rasters:
-                pl.store(raster_refs[i],
-                         (pl.dslice(t, 1), slice(None), slice(None)),
-                         cur[None])
+                raster_refs[i][pl.ds(t, 1)] = cur[None]
         if has_readout:
             # readout: wide int32 accumulate, no 11b clamp
             v_out = accumulate(n_spiking, cur)
@@ -445,17 +467,22 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
     for w in ws:
         out_specs.append(pl.BlockSpec((block_b, w.shape[1]), lambda b: (b, 0)))
         out_shape.append(jax.ShapeDtypeStruct((B, w.shape[1]), jnp.int32))
-    if sparse:
-        out_specs.append(pl.BlockSpec((1, skip_lanes), lambda b: (b, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((B // block_b, skip_lanes),
+    # per-tile counter rows: (tiles, 1, X) arrays in (1, X) blocks (the
+    # tile axis squeezed) — a (1, X) block of a (tiles, X) array breaks the
+    # TPU tiling rule (second-minor block dim a multiple of 8 or the whole
+    # dim) as soon as the grid has more than one tile
+    def counter(width):
+        out_specs.append(pl.BlockSpec((pl.Squeezed(), 1, width),
+                                      lambda b: (b, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((B // block_b, 1, width),
                                               jnp.int32))
+
+    if sparse:
+        counter(skip_lanes)
     if events:
         for w in ws:
-            out_specs.append(pl.BlockSpec((1, w.shape[0]), lambda b: (b, 0)))
-            out_shape.append(jax.ShapeDtypeStruct((B // block_b, w.shape[0]),
-                                                  jnp.int32))
-        out_specs.append(pl.BlockSpec((1, LANE), lambda b: (b, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((B // block_b, LANE), jnp.int32))
+            counter(w.shape[0])
+        counter(LANE)
 
     scratch = [pltpu.VMEM((block_b, w.shape[1]), jnp.int32) for w in ws]
 
@@ -471,10 +498,10 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
     outs = list(outs)
     skips = None
     if sparse:
-        skips = outs.pop()[:, :sum(n_cols)]
+        skips = outs.pop()[:, 0, :sum(n_cols)]
     elif events:
-        fallbacks = outs.pop()[:, :len(ws)]
-        row_counts = outs[-len(ws):]
+        fallbacks = outs.pop()[:, 0, :len(ws)]
+        row_counts = [rc[:, 0] for rc in outs[-len(ws):]]
         del outs[-len(ws):]
         skips = (row_counts, fallbacks)
     rasters = outs[:n_spiking] if emit_rasters else []

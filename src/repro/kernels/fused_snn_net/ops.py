@@ -28,11 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                     # moved out of experimental in 0.6
-    from jax.experimental.shard_map import shard_map
-except ImportError:                      # pragma: no cover - newer jax
-    from jax import shard_map
-
 from repro.kernels.fused_snn_net.kernel import (fused_snn_net_pallas,
                                                 skip_layout)
 
@@ -349,10 +344,10 @@ def _fused_snn_net_mesh_core(spikes, ws, v_init, *, mesh, thresholds, leaks,
                        else P("data"))
         else:
             sk_spec = None
-        rasters, v_finals, skips = shard_map(
+        rasters, v_finals, skips = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs,
             out_specs=(r_spec, v_spec, sk_spec),
-            check_rep=False)(s, list(ws), vi)
+            check_vma=False)(s, list(ws), vi)
         return ([r[:, :B] for r in rasters], [v[:B] for v in v_finals],
                 skips)
 
@@ -394,10 +389,10 @@ def _fused_snn_net_mesh_core(spikes, ws, v_init, *, mesh, thresholds, leaks,
     r_spec = [P(None, "data", None)] * (n_spiking if emit_rasters else 0)
     v_spec = [P("data")] * len(ws)
     c_spec = [P(None)] * len(ws) if use_events else []
-    rasters, v_finals, counts = shard_map(
+    rasters, v_finals, counts = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs,
         out_specs=(r_spec, v_spec, c_spec),
-        check_rep=False)(s, ws_p, vi)
+        check_vma=False)(s, ws_p, vi)
     return ([r[:, :B] for r in rasters], [v[:B] for v in v_finals],
             counts if use_events else None)
 

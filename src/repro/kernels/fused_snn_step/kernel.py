@@ -52,8 +52,7 @@ def _snn_kernel(spikes_ref, w_ref, params_ref, out_ref, v_ref, *,
             v = clamp_v(jnp.where(fired, v - threshold, v), clamp_mode)
         else:                                                 # ResetV
             v = jnp.where(fired, reset, v)
-        pl.store(out_ref, (pl.dslice(t, 1), slice(None), slice(None)),
-                 fired.astype(jnp.int8)[None])
+        out_ref[pl.ds(t, 1)] = fired.astype(jnp.int8)[None]
         return v
 
     v0 = jnp.zeros(v_ref.shape, jnp.int32)

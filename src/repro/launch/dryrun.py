@@ -305,9 +305,7 @@ def _compile_cell(run: RunConfig, mesh):
 
 
 def _measure(compiled) -> dict:
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):       # jax < 0.5: one dict per device
-        ca = ca[0] if ca else {}
+    ca = compiled.cost_analysis() or {}
     coll = parse_collectives(compiled.as_text())
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),

@@ -17,13 +17,10 @@ ICI_BW = 50e9                   # bytes/s per link (~)
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: axis_types (and AxisType) only
-    exist from jax 0.5; older jax builds the same Auto-typed mesh without
-    the kwarg."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis Auto-typed (sharding propagates
+    through the compiler rather than the type system)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -26,6 +26,9 @@ over the data axis, row-tiled macro fan-in over the model axis, and the
 outputs stay bit-identical to the single-device drain (docs/serving.md
 §Mesh). The devices must exist before jax initialises — on CPU launch
 with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
+
+The Pallas backends compile for the device JAX runs on; ``--interpret``
+runs them in the Pallas interpreter instead, to rehearse on a CPU.
 """
 from __future__ import annotations
 
@@ -37,6 +40,7 @@ import numpy as np
 
 from repro.configs.impulse_snn import get_snn_config
 from repro.core import energy, pipeline, snn
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import SNNRequest, SNNServeEngine
 
 
@@ -100,7 +104,11 @@ def main(argv=None):
                          "--xla_force_host_platform_device_count first)")
     ap.add_argument("--quick", action="store_true",
                     help="reduced sizes (CI serving smoke)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run Pallas backends in the interpreter (CPU "
+                         "rehearsal)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     mesh = None
     if args.mesh:
@@ -122,7 +130,8 @@ def main(argv=None):
     eng = SNNServeEngine(program, batch_slots=args.slots,
                          backend=args.backend,
                          step_kw=({"interpret": True}
-                                  if args.backend.startswith("pallas")
+                                  if args.interpret
+                                  and args.backend.startswith("pallas")
                                   else {}),
                          pages=args.pages, megastep=args.megastep,
                          double_buffer=args.double_buffer, mesh=mesh)
@@ -136,9 +145,12 @@ def main(argv=None):
     dt = time.perf_counter() - t0
     frames = sum(r.ticks for r in done)
     rep = eng.aggregate_report()
+    dev = jax.devices()[0]
     print(f"served {len(done)} requests, {frames} frames in {dt:.2f}s "
           f"({frames / dt:.1f} frames/s, "
-          f"{frames / cfg.timesteps / dt:.1f} words/s on CPU; "
+          f"{frames / cfg.timesteps / dt:.1f} words/s on {dev.platform} "
+          f"{dev.device_kind}"
+          f"{' (interpret)' if args.interpret else ''}; "
           f"K={args.megastep}, {args.pages} page(s) x {args.slots} lanes"
           + (f", mesh data={args.mesh.split(',')[0]} "
              f"model={args.mesh.split(',')[1]}" if args.mesh else "") + ")")

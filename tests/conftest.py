@@ -1,9 +1,8 @@
-"""Test-session setup: make `src/` importable and gate optional deps.
+"""Test-session setup: make `src/` importable and force host devices.
 
 The tier-1 command runs with PYTHONPATH=src (also set via pytest.ini
 ``pythonpath``); the sys.path insert below keeps direct `pytest tests/...`
-invocations working from any cwd. The hypothesis fallback keeps the
-property tests runnable in the hermetic container (no pip installs).
+invocations working from any cwd.
 
 The XLA_FLAGS guard forces 4 simulated host devices for the whole test
 session (jax reads the flag at first backend init, so it must be set
@@ -23,13 +22,3 @@ if "XLA_FLAGS" not in os.environ and "jax" not in sys.modules:
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
-
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    import importlib.util
-    _spec = importlib.util.spec_from_file_location(
-        "hypothesis", Path(__file__).parent / "_hypothesis_compat.py")
-    _mod = importlib.util.module_from_spec(_spec)
-    sys.modules["hypothesis"] = _mod     # register first: dataclasses resolve
-    _spec.loader.exec_module(_mod)       # __module__ via sys.modules at exec

@@ -22,12 +22,6 @@ def run_subprocess(body: str, devices: int = 8) -> dict:
         import json
         import jax, jax.numpy as jnp
         import numpy as np
-        if not hasattr(jax.sharding, "AxisType"):   # jax < 0.5 compat shim
-            class _AxisType:
-                Auto = None
-            jax.sharding.AxisType = _AxisType
-            _mm = jax.make_mesh
-            jax.make_mesh = lambda *a, axis_types=None, **k: _mm(*a, **k)
         {textwrap.indent(textwrap.dedent(body), '        ').strip()}
         print("RESULT:" + json.dumps(result))
     """)
@@ -62,7 +56,6 @@ def test_compressed_allreduce_error_feedback():
     """int8-wire mean-reduce == fp32 mean within quant error; error feedback
     makes the BIAS vanish across steps (sum of deq errors -> 0)."""
     res = run_subprocess("""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist.compress import compressed_psum_mean
         mesh = jax.make_mesh((8,), ("data",),
@@ -74,8 +67,8 @@ def test_compressed_allreduce_error_feedback():
             out, r2 = compressed_psum_mean({"w": g[0]}, {"w": r[0]}, "data")
             return out["w"][None], r2["w"][None]
 
-        f = shard_map(step, mesh=mesh, in_specs=(P("data"), P("data")),
-                      out_specs=(P("data"), P("data")), check_rep=False)
+        f = jax.shard_map(step, mesh=mesh, in_specs=(P("data"), P("data")),
+                          out_specs=(P("data"), P("data")), check_vma=False)
         r = jnp.zeros((8, 64), jnp.float32)
         true_mean = g_global.mean(0)
         errs, acc = [], jnp.zeros((8, 64))

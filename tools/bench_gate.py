@@ -14,12 +14,11 @@ Policy (what fails vs what only reports):
     ``fc_skipped_tiles``, ``conv_skipped_tiles``, ``tile``, ``block<G>``,
     ``events``, ``skipped_rows``, ``pallas_events``) are the executed
     sparsity win this repo exists to keep;
-    on the python/jax pin that generated the baseline they are
-    deterministic (seeded rasters, seeded training), so a drop means
-    gating got coarser or stopped firing. Gains are fine. Rows derived
-    from float training are NOT bit-stable across jax versions — CI runs
-    the hard gate only on the baseline leg of its matrix and keeps the
-    other legs report-only.
+    on the jax pin that generated the baseline they are deterministic
+    (seeded rasters, seeded training), so a drop means gating got
+    coarser or stopped firing. Gains are fine. Seeded weights and rows
+    derived from float training are NOT bit-stable across jax versions:
+    regenerate the baseline when CI's jax pin moves.
   * FAIL — an instruction count (``instr``) drifted more than
     ``--rel-tol-instr`` in either direction, or a calibrated energy-model
     number (``energy``, ``E/op``, ``E/inference``, ``TOPS/W``,
