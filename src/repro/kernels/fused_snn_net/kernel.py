@@ -486,6 +486,8 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
 
     scratch = [pltpu.VMEM((block_b, w.shape[1]), jnp.int32) for w in ws]
 
+    # ``name`` fixes the custom call's HLO instruction name, so the
+    # kernel's trace events read ``fused_snn_net.N`` under any enclosing jit
     outs = pl.pallas_call(
         kernel,
         grid=grid,
@@ -494,6 +496,7 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="fused_snn_net",
     )(spikes, *ws, params, *(v_init if v_init is not None else ()))
     outs = list(outs)
     skips = None

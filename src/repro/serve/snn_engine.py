@@ -55,6 +55,12 @@ request's lane keeps integrating its remaining staged frames until the
 block ends; the request's own accounting discards them, the device ledger
 cannot) and LIF wrap-mode leak wraparound on very long idle stretches —
 which is why per-request accounting stays raster-based.
+
+Every tick is annotated for `jax.profiler`: one ``snn_engine.step`` step
+span per `step` call, and phase spans inside it (``admit``, ``stage``,
+``dispatch``, ``stage_next``, ``retire`` with its ``fetch``, ``account``
+and ``evict`` children; docs/serving.md "Tracing"). With no trace being
+recorded a span costs about a microsecond and changes no output.
 """
 from __future__ import annotations
 
@@ -66,6 +72,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core import pipeline
 from repro.core.pipeline import SNNProgram, SparsityReport
@@ -296,6 +303,7 @@ class SNNServeEngine(SlotEngine):
                              if program.layers[0].kind == "conv"
                              else tuple(program.layers[0].state_shape))
         self.ticks = 0                    # engine ticks executed (dispatches)
+        self.steps = 0                    # step() calls, idle ones included
         self.clock = 0                    # frame clock: K per engine tick
         # jit the per-page megastep dispatch (the LM engine jits its
         # decode_step the same way): block/counts shapes are fixed per
@@ -373,38 +381,44 @@ class SNNServeEngine(SlotEngine):
             # slot or runs a spurious tick — keep draining the queue until
             # one actually needs ticks
             while not self.queue.empty():
-                if self.queue.peek().arrival_tick > self.clock:
+                head = self.queue.peek()
+                if head.arrival_tick > self.clock:
                     return    # FIFO: the head gates later submissions too
-                req = self.queue.get()
-                if self._tick_budget(req) == 0:
-                    req.logits = np.zeros(self._n_out[-1], np.float32)
-                    # shape-consistent readout V so degenerate requests
-                    # finalize like every other finish (backend-native
-                    # dtype: f32 float backend, int32 macro readout)
-                    req.v_out = np.zeros(
-                        self._n_out[-1],
-                        np.float32 if self.backend == "float" else np.int32)
-                    req.finish_clock = self.clock
-                    if self.track_events:   # reports only when accounting
-                        req.report = self._finalize_report(_Slot(
-                            req=req, row_events=[np.zeros(n, np.int64)
-                                                 for n in self._n_in]))
-                    self.finished.append(req)
-                    continue
-                # admit-by-lane-copy: the fresh request's (zero) V tree
-                # enters the slot's lane; the V_MEM lane is the KV-cache
-                # analogue
-                page, lane = divmod(i, self.B)
-                self.states[page] = lane_scatter(
-                    self._fresh, self.states[page], self._batch_axes, lane)
-                slot.req = req
-                slot.cursor = 0
-                slot.ticks = 0
-                slot.serial = self._admit_seq
-                self._admit_seq += 1
-                slot.row_events = [np.zeros(n, np.int64)
-                                   for n in self._n_in]
-                break
+                with TraceAnnotation("snn_engine.admit", rid=head.rid):
+                    if self._admit_one(i, slot):
+                        break
+
+    def _admit_one(self, i: int, slot: _Slot) -> bool:
+        """Pop the queue head into free slot ``i``; False when it needs no
+        ticks and finished at admission, leaving the slot free."""
+        req = self.queue.get()
+        if self._tick_budget(req) == 0:
+            req.logits = np.zeros(self._n_out[-1], np.float32)
+            # shape-consistent readout V so degenerate requests finalize
+            # like every other finish (backend-native dtype: f32 float
+            # backend, int32 macro readout)
+            req.v_out = np.zeros(
+                self._n_out[-1],
+                np.float32 if self.backend == "float" else np.int32)
+            req.finish_clock = self.clock
+            if self.track_events:   # reports only when accounting
+                req.report = self._finalize_report(_Slot(
+                    req=req, row_events=[np.zeros(n, np.int64)
+                                         for n in self._n_in]))
+            self.finished.append(req)
+            return False
+        # admit-by-lane-copy: the fresh request's (zero) V tree enters the
+        # slot's lane; the V_MEM lane is the KV-cache analogue
+        page, lane = divmod(i, self.B)
+        self.states[page] = lane_scatter(
+            self._fresh, self.states[page], self._batch_axes, lane)
+        slot.req = req
+        slot.cursor = 0
+        slot.ticks = 0
+        slot.serial = self._admit_seq
+        self._admit_seq += 1
+        slot.row_events = [np.zeros(n, np.int64) for n in self._n_in]
+        return True
 
     # -- per-slot event accounting ------------------------------------------
     def _account(self, rasters: list, served: list) -> None:
@@ -561,6 +575,11 @@ class SNNServeEngine(SlotEngine):
     def step(self) -> int:
         """One engine tick: admit, then one K-frame megastep per occupied
         page. Returns #active slots remaining after evictions."""
+        with StepTraceAnnotation("snn_engine.step", step_num=self.steps):
+            self.steps += 1
+            return self._tick()
+
+    def _tick(self) -> int:
         self._admit()
         by_page = self.active_by_page()
         if not by_page:
@@ -572,31 +591,37 @@ class SNNServeEngine(SlotEngine):
             return 0
         outs = {}
         for page in sorted(by_page):
-            block, counts = self._stage_block(page)
-            if self._dispatch is not None:
-                self.states[page], flat = self._dispatch(
-                    self.states[page], block, counts)
-                outs[page] = pipeline.MegastepOut(*flat)
-            else:
-                self.states[page], outs[page] = pipeline.stream_megastep(
-                    self.program, self.states[page], block, self.backend,
-                    active=counts, emit_rasters=self.track_events,
-                    mesh=self.mesh, **self.step_kw)
+            with TraceAnnotation("snn_engine.stage", page=page):
+                block, counts = self._stage_block(page)
+            with TraceAnnotation("snn_engine.dispatch", page=page):
+                if self._dispatch is not None:
+                    self.states[page], flat = self._dispatch(
+                        self.states[page], block, counts)
+                    outs[page] = pipeline.MegastepOut(*flat)
+                else:
+                    self.states[page], outs[page] = pipeline.stream_megastep(
+                        self.program, self.states[page], block,
+                        self.backend, active=counts,
+                        emit_rasters=self.track_events, mesh=self.mesh,
+                        **self.step_kw)
         if self.double_buffer:
-            self._stage_next(sorted(by_page))
+            with TraceAnnotation("snn_engine.stage_next"):
+                self._stage_next(sorted(by_page))
         self.ticks += 1
         self.clock += self.K
         for page in sorted(by_page):
-            self._retire_page(page, by_page[page], outs[page])
+            with TraceAnnotation("snn_engine.retire", page=page):
+                self._retire_page(page, by_page[page], outs[page])
         return sum(1 for s in self.slots if s.req is not None)
 
     def _retire_page(self, page: int, lanes: list, out) -> None:
         """Account one page's megastep and finalize the requests that
         finished inside it — from the block's per-tick readout trajectory,
         at the exact tick a K=1 drain would have stopped on."""
-        logits = np.asarray(out.logits_traj)       # (K, B, n_out)
-        v_traj = np.asarray(out.v_out_traj)
-        consumed = np.asarray(out.frames_consumed)
+        with TraceAnnotation("snn_engine.fetch"):
+            logits = np.asarray(out.logits_traj)   # (K, B, n_out)
+            v_traj = np.asarray(out.v_out_traj)
+            consumed = np.asarray(out.frames_consumed)
         served, fins = [], []
         for i in lanes:
             slot = self.slots[i]
@@ -619,26 +644,31 @@ class SNNServeEngine(SlotEngine):
             slot.ticks += credit
             if fin is not None:
                 fins.append((i, lane, fin))
-        if self.track_events and out.rasters is not None:
-            self._account(out.rasters, served)
-        if self._event_backend and out.skips is not None:
-            self._account_device(out)
+        by_rasters = self.track_events and out.rasters is not None
+        by_device = self._event_backend and out.skips is not None
+        if by_rasters or by_device:
+            with TraceAnnotation("snn_engine.account"):
+                if by_rasters:
+                    self._account(out.rasters, served)
+                if by_device:
+                    self._account_device(out)
         for i, lane, fin in fins:
             slot = self.slots[i]
             req = slot.req
-            req.logits = logits[fin, lane].copy()
-            req.v_out = v_traj[fin, lane].copy()
-            req.ticks = slot.ticks
-            req.finish_clock = self.clock - self.K + fin + 1
-            if self.track_events:
-                req.report = self._finalize_report(slot)
-            self.finished.append(req)
-            # idle lanes are silent: re-seed the vacated lane with fresh
-            # zero state so deeper layers cannot keep leaking/firing from
-            # carried V until re-admission
-            self.states[page] = lane_scatter(
-                self._fresh, self.states[page], self._batch_axes, lane)
-            self.slots[i] = _Slot()
+            with TraceAnnotation("snn_engine.evict", rid=req.rid):
+                req.logits = logits[fin, lane].copy()
+                req.v_out = v_traj[fin, lane].copy()
+                req.ticks = slot.ticks
+                req.finish_clock = self.clock - self.K + fin + 1
+                if self.track_events:
+                    req.report = self._finalize_report(slot)
+                self.finished.append(req)
+                # idle lanes are silent: re-seed the vacated lane with
+                # fresh zero state so deeper layers cannot keep
+                # leaking/firing from carried V until re-admission
+                self.states[page] = lane_scatter(
+                    self._fresh, self.states[page], self._batch_axes, lane)
+                self.slots[i] = _Slot()
 
     # run_until_drained (and its EngineUndrained contract) comes from
     # SlotEngine — one drain loop shared with the LM engine.

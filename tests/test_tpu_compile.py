@@ -75,3 +75,25 @@ def test_kernel_compiles_for_v5e(one_chip, mode, v_init):
     step and megastep entry), compiles to a Mosaic custom call."""
     compiled = _compile(one_chip, MODES[mode], v_init)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_kernel_instruction_name_is_stable(one_chip):
+    """The fused kernel's custom call is named ``fused_snn_net`` under any
+    enclosing jit: its trace events are found by that name."""
+    from repro.kernels.fused_snn_net.kernel import fused_snn_net_pallas
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def serve_tick(s, w, p):
+        return fused_snn_net_pallas(s, w, p, neuron="rmp",
+                                    clamp_mode="saturate", block_b=BLOCK_B,
+                                    emit_rasters=False)
+
+    ws = [sds((128, 128), jnp.int8) for _ in range(3)]
+    text = jax.jit(serve_tick).lower(
+        sds((T, BATCH, 128), jnp.int8), ws,
+        sds((2, 2), jnp.int32)).compile().as_text()
+    names = [ln.split(" = ", 1)[0].split()[-1] for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert names == ["%fused_snn_net.1"]
