@@ -67,11 +67,12 @@ from __future__ import annotations
 import weakref
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core import pipeline
@@ -172,14 +173,58 @@ def merge_reports(reports: list) -> SparsityReport:
 _MEGASTEP_JIT = {}  # (id(program), backend, kw, rasters, mesh) -> (ref, fn)
 
 
+def _pack_lanes(out: pipeline.MegastepOut) -> jax.Array:
+    """The per-lane results the host reads after a megastep, as one
+    lane-major int32 array of shape (B, 2·K·n_out + 1): the block's
+    logit trajectory (bit-cast from float32, exact), its readout V
+    trajectory, then frames consumed. The lane axis stays first, so a
+    mesh engine's packed output is sharded over ``data`` like the state.
+    `_unpack_lanes` is the host-side inverse."""
+    for name, a, dtype in (("logits_traj", out.logits_traj, jnp.float32),
+                           ("v_out_traj", out.v_out_traj, jnp.int32),
+                           ("frames_consumed", out.frames_consumed,
+                            jnp.int32)):
+        if a.dtype != dtype:    # refuse, never convert: the packing is exact
+            raise TypeError(f"{name} is {a.dtype}; packing needs {dtype}")
+    k, b, n_out = out.v_out_traj.shape
+
+    def lane_major(a):
+        return jnp.moveaxis(a, 1, 0).reshape(b, k * n_out)
+    return jnp.concatenate(
+        [lax.bitcast_convert_type(lane_major(out.logits_traj), jnp.int32),
+         lane_major(out.v_out_traj), out.frames_consumed[:, None]], axis=1)
+
+
+def _unpack_lanes(host: np.ndarray, k: int, n_out: int) -> tuple:
+    """`_pack_lanes`'s host array -> ((K, B, n_out) float32 logits,
+    (K, B, n_out) int32 readout V, (B,) frames consumed), as views."""
+    b, kn = host.shape[0], k * n_out
+    logits = host[:, :kn].view(np.float32).reshape(b, k, n_out)
+    v_traj = host[:, kn:2 * kn].reshape(b, k, n_out)
+    return logits.swapaxes(0, 1), v_traj.swapaxes(0, 1), host[:, -1]
+
+
+class _PackedOut(NamedTuple):
+    """What the jitted megastep core returns after the new state:
+    `_pack_lanes` of the block, then the outputs kept apart (rasters only
+    with ``track_events``)."""
+    packed: jax.Array
+    rasters: Optional[list]
+    skips: Any
+    conv_skips: Any
+
+
 def _jit_megastep(program, backend, step_kw, emit_rasters, mesh=None):
     """Jitted megastep core shared across engines over the same program.
 
     SNNProgram is frozen and holds device arrays (unhashable), so the
     cache is keyed by ``id`` with a weakref guard against id reuse (a
     `jax.sharding.Mesh` IS hashable, so ``mesh`` keys directly). The
-    core returns ``MegastepOut``'s fields as a tuple (the dataclass is
-    not a pytree); callers rebuild it.
+    core returns ``(state, packed, rasters, skips, conv_skips)``: the
+    per-lane results the host reads come back as one packed array
+    (`_pack_lanes`), so retiring a page is one device→host read, and the
+    block's final readout V and logits, which the engine never reads, are
+    no outputs at all.
     """
     key = (id(program), backend, tuple(sorted(step_kw.items())),
            emit_rasters, mesh)
@@ -191,9 +236,7 @@ def _jit_megastep(program, backend, step_kw, emit_rasters, mesh=None):
         st2, out = pipeline.stream_megastep(
             program, st, block, backend, active=counts,
             emit_rasters=emit_rasters, mesh=mesh, **step_kw)
-        return st2, (out.v_out, out.logits, out.v_out_traj,
-                     out.logits_traj, out.frames_consumed,
-                     out.rasters, out.skips, out.conv_skips)
+        return st2, _pack_lanes(out), out.rasters, out.skips, out.conv_skips
 
     fn = jax.jit(_core)
     _MEGASTEP_JIT[key] = (weakref.ref(program), fn)
@@ -310,12 +353,11 @@ class SNNServeEngine(SlotEngine):
         # engine config, so this compiles once. The event-list executors
         # fold their ledgers to host numpy inside the op wrapper and the
         # float backend's QAT ops are kept eager for bit-identity with
-        # stream_step — those take the direct path. MegastepOut is a
-        # plain dataclass, not a pytree, so the jitted core returns its
-        # fields as a tuple. The compiled core is cached per (program,
-        # backend, step_kw) so every engine over the same program — the
-        # warmup drain in the benchmark, a restarted server — shares one
-        # compile instead of retracing a fresh closure.
+        # stream_step — those take the direct path. The compiled core is
+        # cached per (program, backend, step_kw) so every engine over the
+        # same program — the warmup drain in the benchmark, a restarted
+        # server — shares one compile instead of retracing a fresh
+        # closure.
         self._dispatch = None
         if backend not in ("float", "ref_events", "pallas_events"):
             self._dispatch = _jit_megastep(program, backend, self.step_kw,
@@ -328,6 +370,7 @@ class SNNServeEngine(SlotEngine):
         self.device_row_events: Optional[list] = None
         self.device_dense_fallbacks: Optional[list] = None
         self.device_ticks = 0             # frame ticks dispatched, all pages
+        self.device_reads = 0             # device->host array reads in fetch
 
     @property
     def state(self) -> pipeline.StreamState:
@@ -595,9 +638,9 @@ class SNNServeEngine(SlotEngine):
                 block, counts = self._stage_block(page)
             with TraceAnnotation("snn_engine.dispatch", page=page):
                 if self._dispatch is not None:
-                    self.states[page], flat = self._dispatch(
+                    self.states[page], *rest = self._dispatch(
                         self.states[page], block, counts)
-                    outs[page] = pipeline.MegastepOut(*flat)
+                    outs[page] = _PackedOut(*rest)
                 else:
                     self.states[page], outs[page] = pipeline.stream_megastep(
                         self.program, self.states[page], block,
@@ -614,14 +657,28 @@ class SNNServeEngine(SlotEngine):
                 self._retire_page(page, by_page[page], outs[page])
         return sum(1 for s in self.slots if s.req is not None)
 
+    def _fetch(self, out) -> tuple:
+        """Read one page's per-lane results to the host: (K, B, n_out)
+        logit and readout V trajectories and (B,) frames consumed. The
+        jitted path reads its packed array in one transfer; the eager
+        path (a `MegastepOut`) reads the three arrays one by one."""
+        if isinstance(out, _PackedOut):
+            with TraceAnnotation("snn_engine.fetch", reads=1):
+                host = np.asarray(out.packed)
+            self.device_reads += 1
+            return _unpack_lanes(host, self.K, self._n_out[-1])
+        with TraceAnnotation("snn_engine.fetch", reads=3):
+            fetched = (np.asarray(out.logits_traj),
+                       np.asarray(out.v_out_traj),
+                       np.asarray(out.frames_consumed))
+        self.device_reads += 3
+        return fetched
+
     def _retire_page(self, page: int, lanes: list, out) -> None:
         """Account one page's megastep and finalize the requests that
         finished inside it — from the block's per-tick readout trajectory,
         at the exact tick a K=1 drain would have stopped on."""
-        with TraceAnnotation("snn_engine.fetch"):
-            logits = np.asarray(out.logits_traj)   # (K, B, n_out)
-            v_traj = np.asarray(out.v_out_traj)
-            consumed = np.asarray(out.frames_consumed)
+        logits, v_traj, consumed = self._fetch(out)
         served, fins = [], []
         for i in lanes:
             slot = self.slots[i]

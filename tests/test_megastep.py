@@ -20,6 +20,8 @@ zero-budget requests finish with a shape-consistent zero ``v_out``, and
 ``aggregate_report`` raises the named ``ReportUnavailable`` instead of a
 generic merge error.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,7 +31,7 @@ from repro.configs.base import SpikingConfig
 from repro.configs.impulse_snn import SNNModelConfig
 from repro.core import pipeline, snn
 from repro.serve import ReportUnavailable, SNNRequest, SNNServeEngine
-from repro.serve.snn_engine import merge_reports
+from repro.serve.snn_engine import _pack_lanes, _unpack_lanes, merge_reports
 
 LENET_S = SNNModelConfig(
     arch_id="lenet-s",
@@ -369,3 +371,144 @@ def test_aggregate_report_named_errors():
     eng2 = SNNServeEngine(program, batch_slots=1, backend="int_ref")
     with pytest.raises(ReportUnavailable, match="finished"):
         eng2.aggregate_report()
+
+
+# ---------------------------------------------------------------------------
+# the packed fetch: a jitted engine reads each page's results in one
+# device->host transfer
+# ---------------------------------------------------------------------------
+
+def _count_dispatches(eng):
+    """Wrap a jitted engine's dispatch to count page dispatches."""
+    calls = []
+    inner = eng._dispatch
+
+    def counted(*args):
+        calls.append(1)
+        return inner(*args)
+    eng._dispatch = counted
+    return calls
+
+
+def _mid_block_exit(program, frames, k):
+    """A stop threshold whose first crossing is a new |logit| maximum at a
+    tick that does not end a K-block, and the ticks it leaves served."""
+    state = program.init_state(1, "int_ref")
+    traj = []
+    for t in range(len(frames)):
+        state, out = program.step(state, jnp.asarray(frames[t:t + 1]),
+                                  "int_ref")
+        traj.append(float(np.max(np.abs(np.asarray(out.logits)))))
+    for t in range(1, len(traj)):
+        if (t + 1) % k and traj[t] > max(traj[:t]):
+            return traj[t], t + 1
+    raise AssertionError("no mid-block crossing in this stream")
+
+
+@pytest.mark.parametrize("backend,kw", [
+    ("int_ref", {}),
+    ("pallas", {"interpret": True, "block_b": 2}),
+])
+@pytest.mark.parametrize("megastep,pages", [(1, 1), (3, 2)])
+@pytest.mark.parametrize("n_out", [1, 3])
+def test_engine_packed_fetch_equals_isolated(backend, kw, megastep, pages,
+                                             n_out):
+    """On the jitted path each dispatched page's results come back in one
+    packed read (``device_reads`` == page dispatches), and every request's
+    logits, readout V, ticks and finish clock equal an isolated run of the
+    frames it served: full streams, a tick budget that ends mid-block and
+    a confidence exit mid-block. All requests fit the pool at once, so
+    each is admitted at clock 0 and finishes at clock == ticks."""
+    cfg = SNNModelConfig(
+        arch_id="test", layer_sizes=(37, 50, 20, n_out),
+        spiking=SpikingConfig(neuron="rmp", timesteps=3, threshold=1.0,
+                              leak=0.0625, w_bits=6, v_bits=11),
+        timesteps=3)
+    params = snn.init_fc_snn(jax.random.PRNGKey(5), cfg)
+    program = pipeline.compile_network(cfg, params, domain="int")
+    reqs = [_word_request(cfg, 0, 4, seed=70),
+            _word_request(cfg, 1, 3, seed=71, max_ticks=7),
+            _word_request(cfg, 2, 2, seed=72),
+            _word_request(cfg, 3, 5, seed=73)]
+    thr, exit_ticks = _mid_block_exit(program, reqs[3].frames, 3)
+    reqs[3].stop_threshold = thr
+    want_ticks = [12, 7, 6, exit_ticks]
+    eng = SNNServeEngine(program, batch_slots=4 // pages, backend=backend,
+                         step_kw=kw, pages=pages, megastep=megastep,
+                         track_events=False)
+    calls = _count_dispatches(eng)
+    for r in reqs:
+        eng.submit(r)
+    done = sorted(eng.run_until_drained(), key=lambda r: r.rid)
+    assert eng.device_reads == len(calls) > 0
+    for r, ticks in zip(done, want_ticks):
+        assert (r.ticks, r.finish_clock) == (ticks, ticks), r.rid
+        iso = pipeline.run_network(
+            program, jnp.asarray(r.frames[:ticks, None]), "int_ref")
+        assert r.v_out.dtype == np.int32 and r.logits.dtype == np.float32
+        np.testing.assert_array_equal(r.v_out, np.asarray(iso.v_out)[0],
+                                      err_msg=f"rid {r.rid} v_out")
+        np.testing.assert_array_equal(r.logits, np.asarray(iso.logits)[0],
+                                      err_msg=f"rid {r.rid} logits")
+
+
+def _block_out(k=3, b=4, n_out=2, seed=0):
+    rng = np.random.default_rng(seed)
+    logits = rng.standard_normal((k, b, n_out)).astype(np.float32)
+    logits.flat[:4] = [-0.0, np.inf, 1e-45, np.nan]    # bits, not values
+    return pipeline.MegastepOut(
+        v_out=None, logits=None,
+        v_out_traj=jnp.asarray(rng.integers(-2**31, 2**31 - 1,
+                                            (k, b, n_out), np.int32)),
+        logits_traj=jnp.asarray(logits),
+        frames_consumed=jnp.asarray(rng.integers(0, k + 1, b, np.int32)))
+
+
+def test_pack_lanes_round_trip_is_bit_exact():
+    """`_unpack_lanes(_pack_lanes(out))` gives back every bit of the
+    trajectories and the frame counts, special floats included."""
+    out = _block_out()
+    packed = _pack_lanes(out)
+    assert packed.shape == (4, 2 * 3 * 2 + 1) and packed.dtype == jnp.int32
+    logits, v_traj, consumed = _unpack_lanes(np.asarray(packed), 3, 2)
+    np.testing.assert_array_equal(
+        logits.view(np.uint32), np.asarray(out.logits_traj).view(np.uint32))
+    np.testing.assert_array_equal(v_traj, np.asarray(out.v_out_traj))
+    np.testing.assert_array_equal(consumed, np.asarray(out.frames_consumed))
+
+
+@pytest.mark.parametrize("field,dtype", [("v_out_traj", jnp.int16),
+                                         ("logits_traj", jnp.bfloat16),
+                                         ("frames_consumed", jnp.uint8)])
+def test_pack_lanes_refuses_other_dtypes(field, dtype):
+    """A result that is not the 32-bit type the host reads back is
+    refused, never converted."""
+    out = _block_out()
+    bad = dataclasses.replace(
+        out, **{field: getattr(out, field).astype(dtype)})
+    with pytest.raises(TypeError, match=field):
+        _pack_lanes(bad)
+
+
+@pytest.mark.parametrize("backend,reads", [("int_ref", 1), ("ref_events", 3)])
+def test_engine_device_reads_per_page_dispatch(monkeypatch, backend, reads):
+    """``device_reads`` counts the fetch's device->host reads: one packed
+    array per dispatched page on the jitted path, the three result arrays
+    on the eager path."""
+    cfg, program = _program()
+    eng = SNNServeEngine(program, batch_slots=2, backend=backend,
+                         pages=2, megastep=2)
+    if eng._dispatch is not None:
+        calls = _count_dispatches(eng)
+    else:
+        calls, inner = [], pipeline.stream_megastep
+
+        def counted(*args, **kw):
+            calls.append(1)
+            return inner(*args, **kw)
+        monkeypatch.setattr(pipeline, "stream_megastep", counted)
+    for rid, nw in enumerate([2, 1, 3]):
+        eng.submit(_word_request(cfg, rid, nw, seed=80 + rid))
+    eng.run_until_drained()
+    assert len(calls) > eng.ticks         # two pages dispatched some ticks
+    assert eng.device_reads == reads * len(calls)

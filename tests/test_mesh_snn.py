@@ -244,9 +244,20 @@ def _requests(n=7, t=9, d=300, seed=11):
 
 
 def _drain(program, mesh, backend, kw, megastep=4, pages=2):
+    """Drain `_requests` through a paged engine; on the jitted path also
+    keep each page dispatch's packed per-lane results."""
     eng = SNNServeEngine(program, batch_slots=4, backend=backend,
                          step_kw=kw, pages=pages, megastep=megastep,
                          mesh=mesh)
+    eng.packed = []
+    if eng._dispatch is not None:
+        inner = eng._dispatch
+
+        def kept(*args):
+            out = inner(*args)
+            eng.packed.append(out[1])
+            return out
+        eng._dispatch = kept
     for r in _requests():
         eng.submit(r)
     eng.run_until_drained()
@@ -265,12 +276,20 @@ def test_mesh_serving_drain(backend, kw):
     lanes sharded over data=2, rows over model=2, K=4 megasteps) serves
     every request bit-identically to the single-device engine, and the
     event accounting closes: per-request reports, the merged aggregate,
-    and — on the event backends — the device ledger."""
+    and — on the event backends — the device ledger. On the jitted path
+    both engines read each page dispatch's results as one packed array,
+    which the mesh engine keeps sharded over data like its lanes."""
     program, _ = _make()
     mesh = make_host_mesh(4, model=2)
     a = _drain(program, None, backend, kw)
     b = _drain(program, mesh, backend, kw)
     assert len(a.finished) == len(b.finished) == 7
+    if a._dispatch is not None:
+        assert a.device_reads == len(a.packed) > 0
+        assert b.device_reads == len(b.packed) == len(a.packed)
+        for p in b.packed:
+            assert p.sharding.shard_shape(p.shape)[0] == \
+                p.shape[0] // mesh.shape["data"]
     for ra, rb in zip(sorted(a.finished, key=lambda r: r.rid),
                       sorted(b.finished, key=lambda r: r.rid)):
         np.testing.assert_array_equal(ra.logits, rb.logits,
