@@ -2,6 +2,7 @@
 """Bring-up smoke run of the IMPULSE serving path on a TPU.
 
     python chip_smoke.py              # one chip: batch and serving phases
+    python chip_smoke.py --arch rsnn-ssc   # the same phases for one config
     python chip_smoke.py --chips 4    # four chips: the mesh serving phase only
 
 Drives `compile_network` -> the integer Pallas backends -> `SNNServeEngine`
@@ -16,6 +17,12 @@ the `int_ref` word-level reference run in the same process:
   Poisson arrivals, drained by an impulse-imdb engine of 2 pages x 8 lanes
   at megastep K=8 on each Pallas backend; per-request logits must equal
   those of an int_ref engine's drain.
+* ``--arch NAME`` runs the batch and serving phases for that config alone
+  (rsnn-ssc: its two recurrent layers, 700 -> 400r -> 400r -> 35, over
+  50-frame streams at input sparsity 0.95). Backends that do not execute
+  recurrent layers must refuse it by name (``match=refused``); its
+  serving drain is also checked against one batch `run_network` of every
+  request on `int_ref`.
 * mesh (``--chips 4``): the serving requests drained on (4,1) and (2,2)
   meshes — lanes over "data", macro row tiles over "model" with the
   integer-psum AccV2V — must equal the single-device drain.
@@ -39,7 +46,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.configs.impulse_snn import get_snn_config  # noqa: E402
+from repro.analysis import ContractError  # noqa: E402
+from repro.configs.impulse_snn import SNN_CONFIGS, get_snn_config  # noqa: E402
 from repro.core import pipeline, snn  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
@@ -60,6 +68,9 @@ N_WORDS = 6                 # words per stream (imdb) / batch presentation
 N_REQUESTS = 16
 LANES, PAGES, MEGASTEP = 8, 2, 8
 SPARSITY = 0.85
+#: (words per stream, sparsity) where a config differs from the above:
+#: rsnn-ssc presents one frame per word, so its streams are 50 frames
+STREAMS = {"rsnn-ssc": (50, 0.95)}
 POISSON_GAP = 4.0           # mean inter-arrival gap, frame ticks
 
 
@@ -99,7 +110,8 @@ def _batch_inputs(cfg, batch: int, seed: int) -> jax.Array:
     if cfg.conv_spec:
         x = rng.standard_normal((batch, *cfg.in_shape)).astype(np.float32)
         return pipeline.present_static(jnp.asarray(2.0 * x), cfg.timesteps)
-    x = rng.standard_normal((batch, N_WORDS, cfg.layer_sizes[0]))
+    words = STREAMS.get(cfg.arch_id, (N_WORDS,))[0]
+    x = rng.standard_normal((batch, words, cfg.layer_sizes[0]))
     return pipeline.present_words(jnp.asarray(x.astype(np.float32)),
                                   cfg.timesteps)
 
@@ -114,6 +126,10 @@ def batch_phase(arch: str, batch: int, seed: int) -> None:
     for backend, kw in PALLAS_CASES:
         label = backend + "".join(f"[G={g}]" for g in kw.values())
         t0 = time.perf_counter()
+        if _refused(program, backend, lambda: pipeline.run_network(
+                program, xs, backend, **KERNEL_KW, **kw)):
+            _report("batch", arch, label, t0, match="refused", B=batch)
+            continue
         res = pipeline.run_network(program, xs, backend, **KERNEL_KW, **kw)
         where = f"batch {arch} {label}"
         if len(res.rasters) != len(ref.rasters):
@@ -128,13 +144,33 @@ def batch_phase(arch: str, batch: int, seed: int) -> None:
                 block_b=KERNEL_KW["block_b"])
 
 
+def _refused(program, backend: str, call) -> bool:
+    """True when ``program`` has recurrent layers and ``call`` refuses
+    them by name, as a backend without recurrent execution must; a
+    feed-forward program is never refused."""
+    if not program.recurrent_layers:
+        return False
+    try:
+        call()
+    except ContractError as e:
+        if "recurrent" in str(e):
+            return True
+        raise
+    return False
+
+
+def _requests(program, cfg, seed: int) -> list:
+    words, sparsity = STREAMS.get(cfg.arch_id, (N_WORDS, SPARSITY))
+    return make_requests(program, N_REQUESTS, words, cfg.timesteps,
+                         sparsity, seed, poisson_gap=POISSON_GAP)
+
+
 def _drain(program, cfg, backend: str, seed: int, mesh=None) -> dict:
     """Serve the seeded request set to completion; logits by request id."""
     eng = SNNServeEngine(program, batch_slots=LANES, backend=backend,
                          step_kw={} if backend == "int_ref" else KERNEL_KW,
                          pages=PAGES, megastep=MEGASTEP, mesh=mesh)
-    for req in make_requests(program, N_REQUESTS, N_WORDS, cfg.timesteps,
-                             SPARSITY, seed, poisson_gap=POISSON_GAP):
+    for req in _requests(program, cfg, seed):
         eng.submit(req)
     done = eng.run_until_drained()
     if len(done) != N_REQUESTS:
@@ -151,15 +187,28 @@ def _check_drain(where: str, got: dict, want: dict) -> None:
         _check_equal(f"{where} request {rid} logits", got[rid], want[rid])
 
 
-def serve_phase(seed: int) -> None:
-    arch = "impulse-imdb"
+def serve_phase(seed: int, arch: str = "impulse-imdb") -> None:
     cfg, program = _program(arch, seed)
     shape = dict(lanes=LANES, pages=PAGES, K=MEGASTEP, requests=N_REQUESTS)
     t0 = time.perf_counter()
     ref = _drain(program, cfg, "int_ref", seed)
+    if program.recurrent_layers:
+        # every request from rest in one batch run: the drain's admissions,
+        # evictions and carried rasters must not show in any result
+        reqs = _requests(program, cfg, seed)
+        batch = pipeline.run_network(
+            program, jnp.stack([jnp.asarray(r.frames) for r in reqs], 1),
+            "int_ref")
+        _check_drain("serve int_ref vs batch", ref,
+                     {r.rid: np.asarray(batch.logits)[i]
+                      for i, r in enumerate(reqs)})
     _report("serve", arch, "int_ref", t0, match="reference", **shape)
     for backend in SERVE_BACKENDS:
         t0 = time.perf_counter()
+        if _refused(program, backend, lambda: SNNServeEngine(
+                program, batch_slots=LANES, backend=backend)):
+            _report("serve", arch, backend, t0, match="refused", **shape)
+            continue
         _check_drain(f"serve {backend}", _drain(program, cfg, backend, seed),
                      ref)
         _report("serve", arch, backend, t0, **shape)
@@ -189,6 +238,9 @@ def main(argv=None) -> int:
                     help="4 runs only the mesh serving phase on 4 chips")
     ap.add_argument("--seed", type=int, default=0,
                     help="seed of the weights, inputs and requests")
+    ap.add_argument("--arch", choices=sorted(SNN_CONFIGS),
+                    help="run the batch and serving phases for this "
+                         "config alone")
     args = ap.parse_args(argv)
     enable_compile_cache()
     devices = jax.devices()
@@ -201,6 +253,10 @@ def main(argv=None) -> int:
                          f"{len(devices)} device(s)")
     if args.chips == 4:
         mesh_phase(args.seed)
+    elif args.arch:
+        batch_phase(args.arch, dict(BATCH_CASES).get(args.arch, 64),
+                    args.seed)
+        serve_phase(args.seed, args.arch)
     else:
         for arch, batch in BATCH_CASES:
             batch_phase(arch, batch, args.seed)

@@ -40,6 +40,11 @@ the offending call, before any kernel is built:
                       | is rejected here, before the engine's first tick
   mesh_axes           | a mesh-sharded dispatch names "data"/"model"
                       | extents; float/bitmacro have no mesh execution
+  recurrent           | a program with recurrent layers dispatches only
+                      | where they execute: float, int_ref, pallas (dense)
+                      | and bitmacro, on one device or a data-only mesh;
+                      | the gated and event paths and the model axis
+                      | refuse it by name
   mesh_split          | per fused call under model-parallel row tiling:
                       | the padded fan-in divides evenly into per-shard
                       | row tiles (chain alignment is preserved because
@@ -74,10 +79,45 @@ VMEM_BUDGET_BYTES = int(VMEM_BYTES * 0.75)
 PALLAS_BACKENDS = ("pallas", "pallas_sparse", "pallas_events")
 KNOWN_BACKENDS = PALLAS_BACKENDS + ("float", "int_ref", "ref_events",
                                     "bitmacro")
+#: backends that execute recurrent layers (DESIGN.md §3 "Recurrent layers")
+RECURRENT_BACKENDS = ("float", "int_ref", "pallas", "bitmacro")
 
 
 class ContractError(AnalysisError):
     """A kernel contract is violated for this (program, dispatch) pair."""
+
+
+def check_recurrent(program, backend: str, *, use_sparse: bool = False,
+                    mesh=None) -> Optional["ContractCheck"]:
+    """Refuse, with a `ContractError` on contract ``recurrent``, a
+    dispatch that cannot execute ``program``'s recurrent layers: the gated
+    kernel (``pallas_sparse``, ``int_ref(use_sparse=True)``), the event
+    executors (``ref_events``, ``pallas_events``) and a mesh with a model
+    axis (its row-partial tick carries no raster state; a data-only mesh
+    shards the rasters with the lanes). None for a feed-forward
+    program."""
+    rec = [i for i, ly in enumerate(program.layers) if ly.recurrent]
+    if not rec:
+        return None
+    where = f"recurrent layers {rec}"
+    if backend not in RECURRENT_BACKENDS:
+        raise ContractError(
+            f"recurrent: backend {backend!r} does not execute recurrent "
+            f"layers; use one of {RECURRENT_BACKENDS}", where=where)
+    if use_sparse:
+        raise ContractError(
+            "recurrent: the event-gated path (use_sparse) does not execute "
+            "recurrent layers; run the dense int_ref or pallas backend",
+            where=where)
+    if mesh is not None and _mesh_extents(mesh).get("model", 1) > 1:
+        raise ContractError(
+            "recurrent: the model axis' row-partial tick does not carry "
+            "the recurrent spike state; use a data-only mesh (model "
+            "extent 1) or one device", where=where)
+    return ContractCheck(
+        "recurrent", where,
+        f"{len(rec)} recurrent layer(s) on {backend!r}: fan-in n_in + n_out "
+        "W_MEM rows, the last raster carried beside V")
 
 
 @dataclass(frozen=True)
@@ -162,7 +202,8 @@ def _check_chain(program, checks: list) -> None:
 def _call_vmem_bytes(widths: tuple, *, n_spiking: int, frames: int,
                      block_b: int, backend: str, gate_granularity: int,
                      emit_rasters: bool, streaming: bool,
-                     staged_in_elems: int = 0) -> int:
+                     staged_in_elems: int = 0, recurrent: tuple = ()
+                     ) -> int:
     """VMEM bytes resident in one grid step of one fused call.
 
     ``staged_in_elems`` — raw input elements per frame of the streamed
@@ -171,6 +212,10 @@ def _call_vmem_bytes(widths: tuple, *, n_spiking: int, frames: int,
     K frames of every lane as one ``(K, B, *in_shape)`` float32 block
     alongside the kernel's own operands, so its residency scales with K
     too; pass it for the call that consumes the staged block (the first).
+
+    ``recurrent`` (one flag per spiking layer) adds, per recurrent layer,
+    its W_rec tile (Nop x Nop int8) and its raster's int32 scratch,
+    ``s_init`` and final-raster blocks.
     """
     inp = _pad_lane(widths[0])
     outs = [_pad_lane(w) for w in widths[1:]]
@@ -184,6 +229,9 @@ def _call_vmem_bytes(widths: tuple, *, n_spiking: int, frames: int,
         n += frames * block_b * staged_in_elems * 4  # staged frame block
     if emit_rasters:
         n += frames * block_b * sum(outs[:n_spiking])
+    for o, r in zip(outs, recurrent):
+        if r:
+            n += o * o + 3 * block_b * o * 4     # W_rec + raster tiles
     if backend == "pallas_sparse":
         _, _, lanes = skip_layout(tuple(widths[:-1]), gate_granularity)
         n += lanes * 4
@@ -193,6 +241,14 @@ def _call_vmem_bytes(widths: tuple, *, n_spiking: int, frames: int,
         # position map of the tile, per layer
         n += sum(i * i + block_b * i * 4 for i in ins_p)
     return n
+
+
+def call_recurrent(program, name: str) -> tuple:
+    """Recurrent flag per spiking layer of one fused call (``()`` when
+    none is recurrent): the fc stack's hidden layers; conv calls never."""
+    if name != "fc_stack" or not any(ly.recurrent for ly in program.layers):
+        return ()
+    return tuple(s.recurrent for s in program.fc_stack[:-1])
 
 
 def _program_calls(program) -> list:
@@ -280,6 +336,10 @@ def check_kernel_contracts(program, backend: str = "pallas", *,
             f"data={n_data} (lanes/banks partition) x model={n_model} "
             f"(row-tiled fan-in partition); axes {sorted(sizes)}"))
     _check_chain(program, checks)
+    rec_check = check_recurrent(program, backend, use_sparse=use_sparse,
+                                mesh=mesh)
+    if rec_check is not None:
+        checks.append(rec_check)
 
     if gate_granularity not in GATE_GRANULARITIES:
         raise ContractError(
@@ -347,7 +407,8 @@ def check_kernel_contracts(program, backend: str = "pallas", *,
             widths, n_spiking=n_spiking, frames=frames, block_b=block_b,
             backend=backend, gate_granularity=gate_granularity,
             emit_rasters=emit_rasters, streaming=streaming,
-            staged_in_elems=staged_in_elems if ci == 0 else 0)
+            staged_in_elems=staged_in_elems if ci == 0 else 0,
+            recurrent=call_recurrent(program, name))
         if vmem > vmem_budget_bytes:
             raise ContractError(
                 f"vmem_budget: one grid step holds {vmem} bytes resident "

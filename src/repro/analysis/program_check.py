@@ -110,10 +110,12 @@ def _increment_interval(spec, name: str) -> Interval:
     """Per-frame AccW2V sum bound of one layer: hull over output columns of
     [sum_i min(w_ij, 0), sum_i max(w_ij, 0)] — attained by the spike frame
     that activates exactly the negative (resp. positive) rows. With no
-    weights, the worst case over the whole 6-bit grid."""
+    weights, the worst case over the whole 6-bit grid. A recurrent layer's
+    block [W_in; W_rec] is one matrix of n_in + n_out rows, so its bound
+    covers the W_rec rows too (the fan-in the macro accumulates)."""
     w = _weight_matrix(spec)
     if w is None:
-        bound = spec.n_in * W_MAX
+        bound = spec.fan_in * W_MAX
         return Interval(-bound, bound)
     if w.size == 0:
         return Interval.point(0)
@@ -177,7 +179,7 @@ def _check_spiking_layer(idx: int, spec, neuron: str, mode: str
         if mode == "saturate" and not INT32.contains(acc):
             raise RangeError(
                 f"unclamped accumulator {acc} can overflow int32 {INT32} "
-                f"before the saturate clamp (fan-in {spec.n_in}, per-frame "
+                f"before the saturate clamp (fan-in {spec.fan_in}, per-frame "
                 f"increment {inc}); wrap mode would compose through "
                 "overflow, saturate cannot", where=name)
         if mode == "wrap" and not wrap_is_exact(acc):

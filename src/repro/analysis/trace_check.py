@@ -1083,6 +1083,7 @@ def _trace_surfaces(program, backend: str, surfaces: tuple, *, batch: int,
                     gate_granularity: int, event_crossover: float) -> list:
     """[(surface, call, closed_jaxpr, TraceExpectation), ...] for every
     requested dispatch surface of ``backend``."""
+    from repro.analysis.kernel_contracts import call_recurrent
     from repro.kernels.fused_snn_net.ops import (fused_snn_net,
                                                  mesh_padded_widths,
                                                  mesh_rowpartial_tick)
@@ -1092,16 +1093,19 @@ def _trace_surfaces(program, backend: str, surfaces: tuple, *, batch: int,
     out = []
     for name, _names, widths, n_spiking in _program_calls(program):
         ths, lks, readout = _call_params(program, name)
-        ws_sds = [sds((widths[i], widths[i + 1]), jnp.int8)
+        rec = call_recurrent(program, name)
+        # a recurrent layer's block is [W_in; W_rec]: n_out further rows
+        ws_sds = [sds((widths[i] + (widths[i + 1] if i < len(rec) and rec[i]
+                                    else 0), widths[i + 1]), jnp.int8)
                   for i in range(len(widths) - 1)]
         vi_sds = [sds((batch, w), jnp.int32) for w in widths[1:]]
 
-        def run(spikes, ws, vi=None, _t=ths, _l=lks, _r=readout):
+        def run(spikes, ws, vi=None, _t=ths, _l=lks, _r=readout, _rec=rec):
             return fused_snn_net(
                 spikes, ws, thresholds=_t, leaks=_l,
                 neuron=program.neuron, clamp_mode=program.clamp_mode,
                 block_b=block_b, interpret=True, emit_rasters=True,
-                readout=_r, v_init=vi, **flags)
+                readout=_r, v_init=vi, recurrent=_rec, **flags)
 
         expect_kw = dict(neuron=program.neuron,
                          clamp_mode=program.clamp_mode,
@@ -1122,7 +1126,7 @@ def _trace_surfaces(program, backend: str, surfaces: tuple, *, batch: int,
                 # K-frame fused call resuming v_init + the exact readout
                 # trajectory v_init + cumsum(raster @ w_ro)
                 def mega(s, w, v):
-                    r, vf, _sk = run(s, w, v)
+                    r, vf = run(s, w, v)[:2]
                     ro_in = (r[-1] if len(r) else s).astype(jnp.int32)
                     traj = v[-1][None] + jnp.cumsum(
                         ro_in @ w[-1].astype(jnp.int32), axis=0)
@@ -1136,7 +1140,8 @@ def _trace_surfaces(program, backend: str, surfaces: tuple, *, batch: int,
                 vi_sds)
             out.append(("megastep", name, j, TraceExpectation(
                 where=f"{backend}:megastep:{name}", **expect_kw)))
-        if "mesh" in surfaces and mesh_axes:
+        if "mesh" in surfaces and mesh_axes and not rec:
+            # (recurrent programs have no mesh dispatch: check_recurrent)
             sizes = dict(mesh_axes)
             nm = int(sizes.get("model", 1))
             if nm > 1:
@@ -1171,10 +1176,11 @@ def _geometry_signature(program, backend, surfaces, batch, block_b,
                   for name, _ln, widths, ns in _program_calls(program))
     params = tuple((_call_params(program, name)[:2])
                    for name, _ln, _w, _ns in _program_calls(program))
+    recurrent = tuple(ly.recurrent for ly in program.layers)
     return (backend, tuple(surfaces), batch, block_b, megastep_k,
             tuple(mesh_axes), gate_granularity, float(event_crossover),
             program.neuron, program.clamp_mode, int(program.timesteps),
-            calls, params)
+            calls, params, recurrent)
 
 
 #: geometry-keyed memo — equivalence sweeps re-validate identical

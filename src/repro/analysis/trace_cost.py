@@ -186,21 +186,34 @@ def _validate_geometry(program, backend: str, call: str, widths: tuple,
                 f"cost: pallas grid(s) {cost.grids} in '{call}' do not "
                 f"cover batch {batch} in {block_b}-row blocks "
                 f"(want {grid_want})", where=where)
+    from repro.analysis.kernel_contracts import call_recurrent
+    rec = call_recurrent(program, call)
     pw = _padded(widths, backend)
     m_want = batch if backend == "int_ref" else min(block_b, batch)
     for i in range(len(widths) - 1):
-        k_want, n_want = pw[i], pw[i + 1]
-        if backend == "pallas_sparse":
-            hit = [d for d in cost.dots
-                   if d.n == n_want and k_want % d.k == 0]
+        n_want = pw[i + 1]
+        # a recurrent layer contracts its input rows and its W_rec rows:
+        # one concatenated dot on int_ref, two lane-aligned ones in Pallas
+        if i < len(rec) and rec[i]:
+            k_wants = ((pw[i] + pw[i + 1],) if backend == "int_ref"
+                       else (pw[i], pw[i + 1]))
         else:
-            hit = [d for d in cost.dots if d.k == k_want and d.n == n_want]
-        if not hit:
-            raise TraceError(
-                f"cost: no dot_general contracting layer {i} of '{call}' "
-                f"(want K={k_want} N={n_want}; traced "
-                f"{[(d.m, d.k, d.n) for d in cost.dots]}) — the compiled "
-                "path changed shape", where=where)
+            k_wants = (pw[i],)
+        hit = []
+        for k_want in k_wants:
+            if backend == "pallas_sparse":
+                got = [d for d in cost.dots
+                       if d.n == n_want and k_want % d.k == 0]
+            else:
+                got = [d for d in cost.dots
+                       if d.k == k_want and d.n == n_want]
+            if not got:
+                raise TraceError(
+                    f"cost: no dot_general contracting layer {i} of "
+                    f"'{call}' (want K={k_want} N={n_want}; traced "
+                    f"{[(d.m, d.k, d.n) for d in cost.dots]}) — the "
+                    "compiled path changed shape", where=where)
+            hit += got
         if any(d.m != m_want for d in hit):
             raise TraceError(
                 f"cost: dot_general M={sorted({d.m for d in hit})} for "
@@ -247,7 +260,9 @@ def dense_instr(program, batch: int) -> isa.InstrCount:
     layer, frames = T * batch * output-positions and events from the
     SAME-padded patch geometry (conv) or frames * fan-in (fc), through
     the same `count_layer_instructions_from_events` the raster accounting
-    uses."""
+    uses. A recurrent layer's W_rec rows see its own spikes one tick
+    late, so dense they carry (T - 1) * batch * n_out events (s(-1) = 0).
+    """
     T = int(program.timesteps)
     counts = isa.InstrCount()
     conv_maps = iter(_conv_input_maps(program))
@@ -268,9 +283,11 @@ def dense_instr(program, batch: int) -> isa.InstrCount:
         else:
             frames = T * batch
             events = frames * int(spec.n_in)
+            if spec.recurrent:
+                events += (T - 1) * batch * int(spec.n_out)
         neuron = "none" if spec.kind == "readout" else program.neuron
         counts += isa.count_layer_instructions_from_events(
-            events, frames, int(spec.n_in), int(spec.n_out), neuron)
+            events, frames, int(spec.fan_in), int(spec.n_out), neuron)
     return counts
 
 

@@ -5,6 +5,10 @@ These are not LM registry entries; they configure the core/ spiking stack.
                  RMP neurons, 6b W / 11b V_MEM, 10 timesteps. 29.3K params.
   impulse-mnist: modified LeNet-5 with fan-in <= 128 (14 input channels, 3x3
                  kernels => 3*3*14 = 126 <= 128), FC layers < 128 neurons.
+  rsnn-ssc     : two-layer recurrent SNN for Spiking Speech Commands
+                 (arXiv:2103.12593 topology, arXiv:1910.07407 data):
+                 700 cochlea channels -> 400r -> 400r -> 35, RMP neurons,
+                 6b W / 11b V_MEM, one macro timestep per 4 ms input bin.
 """
 from dataclasses import dataclass, field
 
@@ -20,6 +24,15 @@ class SNNModelConfig:
     spiking: SpikingConfig = field(default_factory=SpikingConfig)
     timesteps: int = 10
     task: str = "binary"          # binary | multiclass
+    # per hidden FC layer: True makes it recurrent — its previous-tick
+    # spikes feed back through n_out further W_MEM rows (DESIGN.md §3
+    # "Recurrent layers"); () = all feed-forward
+    recurrent: tuple = ()
+
+    def is_recurrent(self, j: int) -> bool:
+        """Whether hidden FC layer ``j`` (0 = the first after the input)
+        is recurrent."""
+        return j < len(self.recurrent) and bool(self.recurrent[j])
 
 
 IMDB = SNNModelConfig(
@@ -45,7 +58,20 @@ MNIST = SNNModelConfig(
     task="multiclass",
 )
 
-SNN_CONFIGS = {c.arch_id: c for c in (IMDB, MNIST)}
+# Two recurrent hidden layers of 400 (Yin, Corradi & Bohte 2021, their SSC
+# SRNN) on the macro's own RMP neuron and accumulate readout: W_rec is 400
+# further W_MEM rows after each layer's input rows, on the same 6-bit grid.
+RSNN_SSC = SNNModelConfig(
+    arch_id="rsnn-ssc",
+    layer_sizes=(700, 400, 400, 35),
+    spiking=SpikingConfig(neuron="rmp", timesteps=1, threshold=1.0,
+                          leak=0.0625, w_bits=6, v_bits=11),
+    timesteps=1,
+    task="multiclass",
+    recurrent=(True, True),
+)
+
+SNN_CONFIGS = {c.arch_id: c for c in (IMDB, MNIST, RSNN_SSC)}
 
 
 def get_snn_config(arch_id: str) -> SNNModelConfig:

@@ -199,11 +199,26 @@ def neuron_dynamics_int(v: jax.Array, *, neuron: str, threshold: jax.Array,
 
 def layer_timestep_int(v: jax.Array, wq: jax.Array, in_spikes: jax.Array, *,
                        neuron: str, threshold: jax.Array, leak: jax.Array,
-                       reset: jax.Array, clamp_mode: str = "saturate"
+                       reset: jax.Array, clamp_mode: str = "saturate",
+                       recurrent_spikes: jax.Array = None
                        ) -> tuple[jax.Array, jax.Array]:
     """Batched integer timestep: v (..., n_out) int32, wq (n_in, n_out) int8,
     in_spikes (..., n_in) {0,1}. Mathematically == issuing `timestep` per macro
-    tile (tested). Returns (v', out_spikes)."""
+    tile (tested). Returns (v', out_spikes).
+
+    ``recurrent_spikes`` (..., n_out) {0,1} — the layer's own spikes of the
+    previous tick — makes it a recurrent layer: ``wq`` is then the
+    (n_in + n_out, n_out) block [W_in; W_rec], and one AccW2V pass covers
+    the input rows and the n_out recurrent rows after them."""
+    if recurrent_spikes is not None:
+        if wq.shape[0] != in_spikes.shape[-1] + recurrent_spikes.shape[-1]:
+            raise ValueError(
+                f"recurrent layer: weight block has {wq.shape[0]} rows, "
+                f"want {in_spikes.shape[-1]} input + "
+                f"{recurrent_spikes.shape[-1]} recurrent rows")
+        in_spikes = jnp.concatenate(
+            [in_spikes.astype(jnp.int32), recurrent_spikes.astype(jnp.int32)],
+            axis=-1)
     acc = jnp.matmul(in_spikes.astype(jnp.int32), wq.astype(jnp.int32))
     v = clamp_v(v + acc, clamp_mode)
     return neuron_dynamics_int(v, neuron=neuron, threshold=threshold,

@@ -75,12 +75,19 @@ class LayerSpec:
     stride: int = 1               # conv only
     quantize: bool = True         # float domain: fake-quant this layer's w
     state_shape: tuple = ()       # per-example V shape (set at compile)
+    recurrent: bool = False       # fc only: w is [W_in; W_rec], the layer's
+                                  # previous-tick spikes drive the W_rec rows
+
+    @property
+    def fan_in(self) -> int:
+        """W_MEM rows of the layer: n_in, plus n_out recurrent rows."""
+        return self.n_in + self.n_out if self.recurrent else self.n_in
 
     @property
     def tiling(self) -> mapping.FCTiling:
         """This layer's macro-grid tiling (row/col tile counts for the
-        n_in x n_out weight block — `mapping.fc_tiling`)."""
-        return mapping.fc_tiling(self.n_in, self.n_out)
+        fan_in x n_out weight block — `mapping.fc_tiling`)."""
+        return mapping.fc_tiling(self.fan_in, self.n_out)
 
 
 @dataclass(frozen=True)
@@ -112,6 +119,12 @@ class SNNProgram:
         lowered), spiking FCs, readout — the layers instruction counting
         and the integer backends iterate over."""
         return self.int_conv_stack + self.fc_stack
+
+    @property
+    def recurrent_layers(self) -> tuple:
+        """Indices into ``layers`` of the recurrent FC layers, in order —
+        the layers whose last spike raster is carried state beside V."""
+        return tuple(i for i, ly in enumerate(self.layers) if ly.recurrent)
 
     @property
     def neuron_layers(self) -> tuple:
@@ -250,8 +263,17 @@ def compile_network(cfg: SNNModelConfig, params: dict, *, domain: str = "float",
     fc_ws = params["layers"]
     for j, (n_in, n_out) in enumerate(zip(sizes[:-1], sizes[1:])):
         is_readout = j == len(fc_ws) - 1
+        rec = not is_readout and cfg.is_recurrent(j)
         w = fc_ws[j]["w"]
+        if w.shape[0] != (n_in + n_out if rec else n_in):
+            raise ValueError(
+                f"fc layer {j} ({n_in}x{n_out}, "
+                f"{'recurrent' if rec else 'feed-forward'}) needs "
+                f"{n_in + n_out if rec else n_in} weight rows, got "
+                f"{w.shape[0]}; a recurrent layer's block is [W_in; W_rec]")
         if domain == "int":
+            # a recurrent block [W_in; W_rec] quantizes on ONE 6-bit grid:
+            # the W_rec rows are further W_MEM rows of the same macro
             wq, scale = quantize_w(w)
             th_i = None if is_readout else jnp.int32(
                 quantize_neuron_const(float(th[k]), scale, clamp_mode))
@@ -260,12 +282,13 @@ def compile_network(cfg: SNNModelConfig, params: dict, *, domain: str = "float",
             layers.append(LayerSpec(
                 kind="readout" if is_readout else "fc", n_in=n_in, n_out=n_out,
                 w=wq, threshold=th_i, leak=lk_i, scale=float(scale),
-                state_shape=(n_out,)))
+                state_shape=(n_out,), recurrent=rec))
         else:
             layers.append(LayerSpec(
                 kind="readout" if is_readout else "fc", n_in=n_in, n_out=n_out,
                 w=w, threshold=None if is_readout else th[k],
-                leak=None if is_readout else lk[k], state_shape=(n_out,)))
+                leak=None if is_readout else lk[k], state_shape=(n_out,),
+                recurrent=rec))
         if not is_readout:
             k += 1
 
@@ -347,6 +370,9 @@ def run_network(program: SNNProgram, xs: jax.Array, backend: str = "float",
     if backend != "float" and program.domain != "int":
         raise ValueError(f"backend {backend!r} needs an int-domain program "
                          "(compile_network(..., domain='int'))")
+    _refuse_recurrent(program, backend, use_sparse=kw.get("use_sparse",
+                                                          False),
+                      mesh=kw.get("mesh"))
     if backend in ("float", "bitmacro"):
         if kw.pop("mesh", None) is not None:
             raise ValueError(
@@ -356,6 +382,16 @@ def run_network(program: SNNProgram, xs: jax.Array, backend: str = "float",
                 "device backend (int_ref/pallas/pallas_sparse/ref_events/"
                 "pallas_events)")
     return BACKENDS[backend](program, xs, **kw)
+
+
+def _refuse_recurrent(program: SNNProgram, backend: str, *,
+                      use_sparse: bool = False, mesh=None) -> None:
+    """Named refusal (`analysis.ContractError`, contract ``recurrent``)
+    of a dispatch that cannot execute this program's recurrent layers;
+    feed-forward programs pass untouched."""
+    if program.recurrent_layers:
+        from repro.analysis.kernel_contracts import check_recurrent
+        check_recurrent(program, backend, use_sparse=use_sparse, mesh=mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -371,13 +407,16 @@ def _w_float(program: SNNProgram, spec: LayerSpec) -> jax.Array:
     return spec.w
 
 
-def _float_step(program: SNNProgram, vs: list, xt: jax.Array
-                ) -> tuple[list, list]:
-    """One network timestep. Returns (new vs, per-neuron-layer spikes)."""
+def _float_step(program: SNNProgram, vs: list, xt: jax.Array,
+                ss: tuple = ()) -> tuple[list, list, tuple]:
+    """One network timestep. ``ss`` holds the previous tick's spikes of
+    each recurrent layer (program order), which drive its W_rec rows.
+    Returns (new vs, per-neuron-layer spikes, new ss)."""
     neuron = program.neuron
     int_dom = program.domain == "int"
     cur = xt
     vs_new, spikes = [], []
+    ss_prev, ss_new = iter(ss), []
     for i, spec in enumerate(program.layers):
         if spec.kind == "readout":
             if cur.ndim > 2:
@@ -389,6 +428,8 @@ def _float_step(program: SNNProgram, vs: list, xt: jax.Array
         elif spec.kind == "fc":
             if cur.ndim > 2:
                 cur = cur.reshape(cur.shape[0], -1)
+            if spec.recurrent:              # [s_in(t), s(t-1)] @ [W_in; W_rec]
+                cur = jnp.concatenate([cur, next(ss_prev)], axis=-1)
             current = cur @ _w_float(program, spec)
         else:                                     # encoder: identity weight
             current = cur
@@ -412,12 +453,20 @@ def _float_step(program: SNNProgram, vs: list, xt: jax.Array
             v = st.v
         vs_new.append(v)
         spikes.append(s)
+        if spec.recurrent:
+            ss_new.append(s)
         cur = s
-    return vs_new, spikes
+    return vs_new, spikes, tuple(ss_new)
 
 
 def _init_vs(program: SNNProgram, batch: int) -> list:
     return [jnp.zeros((batch, *spec.state_shape)) for spec in program.layers]
+
+
+def _init_spikes(program: SNNProgram, batch: int, dtype) -> tuple:
+    """s(-1) = 0 of every recurrent layer: (batch, n_out) each."""
+    return tuple(jnp.zeros((batch, program.layers[i].n_out), dtype)
+                 for i in program.recurrent_layers)
 
 
 @register_backend("float")
@@ -437,23 +486,25 @@ def run_float(program: SNNProgram, xs: jax.Array, *, return_trace: bool = False,
     n_neuron = len(program.neuron_layers)
 
     def step(carry, xt):
-        vs, sums = carry
-        vs, spikes = _float_step(program, vs, xt)
+        vs, ss, sums = carry
+        vs, spikes, ss = _float_step(program, vs, xt, ss)
         rates = jnp.stack([s.mean() for s in spikes])
         if collect_sums:
             sums = [c + s for c, s in zip(sums, spikes)]
         trace = vs[-1][:, 0] if return_trace else jnp.zeros(B)
         out = (rates, trace, tuple(spikes) if collect_rasters else ())
-        return (vs, sums), out
+        return (vs, ss, sums), out
 
     sums0 = [jnp.zeros((B, *spec.state_shape))
              for spec in program.neuron_layers] if collect_sums else [0.0] * n_neuron
-    carry0 = (_init_vs(program, B), sums0)
+    carry0 = (_init_vs(program, B), _init_spikes(program, B, jnp.float32),
+              sums0)
     if static_input:
-        (vs, sums), (rates, trace, rasters) = jax.lax.scan(
+        (vs, _, sums), (rates, trace, rasters) = jax.lax.scan(
             lambda c, _: step(c, xs), carry0, None, length=program.timesteps)
     else:
-        (vs, sums), (rates, trace, rasters) = jax.lax.scan(step, carry0, xs)
+        (vs, _, sums), (rates, trace, rasters) = jax.lax.scan(step, carry0,
+                                                              xs)
     aux = {"spike_rates": rates, "v_trace": trace}
     if collect_sums:
         aux["spike_sums"] = sums
@@ -528,11 +579,14 @@ def _stack_kernel_args(program: SNNProgram) -> dict:
     """The fused_snn_net argument marshalling shared by int_ref and pallas —
     one place to extend when the stack grows per-layer parameters."""
     stack = program.fc_stack
-    return dict(
+    kw = dict(
         ws=[jnp.asarray(spec.w) for spec in stack],
         thresholds=tuple(int(spec.threshold) for spec in stack[:-1]),
         leaks=tuple(int(spec.leak) for spec in stack[:-1]),
         neuron=program.neuron, clamp_mode=program.clamp_mode)
+    if program.recurrent_layers:      # feed-forward calls keep their key
+        kw["recurrent"] = tuple(spec.recurrent for spec in stack[:-1])
+    return kw
 
 
 def _host_events_sharded(spikes, ws, *, mesh, v_init=None, **kw):
@@ -578,37 +632,49 @@ def _run_fc_stack(program: SNNProgram, spikes: jax.Array, *, use_pallas: bool,
                   use_sparse: bool, block_b: int, interpret: bool,
                   emit_rasters: bool, gate_granularity: int = 1,
                   use_events: bool = False, v_init: Optional[list] = None,
-                  event_crossover: float = 1.0, mesh=None):
+                  event_crossover: float = 1.0, mesh=None,
+                  s_init: Optional[list] = None):
+    """The fc stack's dispatch: (rasters, v_stack, skips, s_stack), with
+    ``s_stack`` the last spike raster of each recurrent layer (empty for
+    a feed-forward stack); ``s_init`` resumes it as ``v_init`` resumes V.
+    Only the dense executors take recurrent layers: every other path
+    refuses them before dispatch (`_refuse_recurrent`)."""
     kw = _stack_kernel_args(program)
+    if "recurrent" in kw:             # feed-forward calls keep their keys
+        kw["s_init"] = s_init
     if mesh is not None:
         if use_events and not use_pallas:    # host spike-list executor
-            return _host_events_sharded(
+            out = _host_events_sharded(
                 spikes, kw.pop("ws"), mesh=mesh,
                 emit_rasters=emit_rasters, v_init=v_init, **kw)
-        from repro.kernels.fused_snn_net.ops import fused_snn_net_mesh
-        return fused_snn_net_mesh(
-            spikes, kw.pop("ws"), mesh=mesh, use_pallas=use_pallas,
-            use_sparse=use_sparse, gate_granularity=gate_granularity,
-            block_b=block_b, interpret=interpret,
-            emit_rasters=emit_rasters, v_init=v_init,
-            use_events=use_events, event_crossover=event_crossover, **kw)
-    if use_events and use_pallas:        # device event-list kernel
+        else:
+            from repro.kernels.fused_snn_net.ops import fused_snn_net_mesh
+            out = fused_snn_net_mesh(
+                spikes, kw.pop("ws"), mesh=mesh, use_pallas=use_pallas,
+                use_sparse=use_sparse, gate_granularity=gate_granularity,
+                block_b=block_b, interpret=interpret,
+                emit_rasters=emit_rasters, v_init=v_init,
+                use_events=use_events, event_crossover=event_crossover,
+                **kw)
+    elif use_events and use_pallas:      # device event-list kernel
         from repro.kernels.fused_snn_net.ops import fused_snn_net_device_events
-        return fused_snn_net_device_events(
+        out = fused_snn_net_device_events(
             spikes, kw.pop("ws"), block_b=block_b, interpret=interpret,
             emit_rasters=emit_rasters, v_init=v_init,
             event_crossover=event_crossover, **kw)
-    if use_events:                       # host spike-list executor
+    elif use_events:                     # host spike-list executor
         from repro.kernels.fused_snn_net.events import fused_snn_net_events
-        return fused_snn_net_events(spikes, kw.pop("ws"),
-                                    emit_rasters=emit_rasters,
-                                    v_init=v_init, **kw)
-    from repro.kernels.fused_snn_net.ops import fused_snn_net
-    return fused_snn_net(
-        spikes, kw.pop("ws"), use_pallas=use_pallas,
-        use_sparse=use_sparse, gate_granularity=gate_granularity,
-        block_b=block_b, interpret=interpret,
-        emit_rasters=emit_rasters, v_init=v_init, **kw)
+        out = fused_snn_net_events(spikes, kw.pop("ws"),
+                                   emit_rasters=emit_rasters,
+                                   v_init=v_init, **kw)
+    else:
+        from repro.kernels.fused_snn_net.ops import fused_snn_net
+        out = fused_snn_net(
+            spikes, kw.pop("ws"), use_pallas=use_pallas,
+            use_sparse=use_sparse, gate_granularity=gate_granularity,
+            block_b=block_b, interpret=interpret,
+            emit_rasters=emit_rasters, v_init=v_init, **kw)
+    return tuple(out) if "recurrent" in kw else (*out, [])
 
 
 def run_stack_from_raster(program: SNNProgram, spikes_enc: jax.Array, *,
@@ -631,7 +697,9 @@ def run_stack_from_raster(program: SNNProgram, spikes_enc: jax.Array, *,
         raise ValueError("run_stack_from_raster executes the fc stack only; "
                          "this program has on-macro conv layers — execute it "
                          "through run_network (int_ref/pallas backends)")
-    rasters, v_stack, skips = _run_fc_stack(
+    _refuse_recurrent(program, "pallas" if use_pallas else "int_ref",
+                      use_sparse=use_sparse)
+    rasters, v_stack, skips, _ = _run_fc_stack(
         program, spikes_enc, use_pallas=use_pallas, use_sparse=use_sparse,
         gate_granularity=gate_granularity, block_b=block_b,
         interpret=interpret, emit_rasters=emit_rasters)
@@ -737,7 +805,7 @@ def _run_macro_stack(program: SNNProgram, xs: jax.Array, *, use_pallas: bool,
         event_crossover=event_crossover, mesh=mesh)
     last = conv_maps[-1] if conv_maps else spikes_enc
     flat = last.reshape(*last.shape[:2], -1) if last.ndim > 3 else last
-    rasters_fc, v_stack, skips = _run_fc_stack(
+    rasters_fc, v_stack, skips, _ = _run_fc_stack(
         program, flat, use_pallas=use_pallas, use_sparse=use_sparse,
         gate_granularity=gate_granularity, use_events=use_events,
         block_b=block_b, interpret=interpret, emit_rasters=emit_rasters,
@@ -947,11 +1015,16 @@ class StreamState(NamedTuple):
     program layer in `program.layers` order (encoder first, readout last),
     each (B, *state_shape). Dtypes are backend-native: all-f32 on the float
     backend, f32 encoder V + int32 macro-stack V on the integer backends.
-    A NamedTuple — hence a pytree — so serving engines can tree-map lane
-    copies over it when admitting/evicting requests."""
+    ``spikes`` is the second kind of carried state: the last spike raster
+    of each recurrent layer (`program.recurrent_layers` order), (B, n_out)
+    each — int8 on the integer backends, f32 on the float backend; empty
+    for a feed-forward program. A NamedTuple — hence a pytree — so serving
+    engines can tree-map lane copies over it when admitting/evicting
+    requests."""
     vs: tuple
     t: int = 0           # ticks executed (bookkeeping; dynamics are
                          # time-invariant, so t never enters the math)
+    spikes: tuple = ()
 
 
 @dataclass
@@ -991,7 +1064,8 @@ def init_stream_state(program: SNNProgram, batch: int,
     for i, spec in enumerate(program.layers):
         dtype = jnp.float32 if (backend == "float" or i == 0) else jnp.int32
         vs.append(jnp.zeros((batch, *spec.state_shape), dtype))
-    return StreamState(vs=tuple(vs), t=0)
+    return StreamState(vs=tuple(vs), t=0, spikes=_init_spikes(
+        program, batch, jnp.float32 if backend == "float" else jnp.int8))
 
 
 def stream_step(program: SNNProgram, state: StreamState, frame: jax.Array,
@@ -1021,10 +1095,12 @@ def stream_step(program: SNNProgram, state: StreamState, frame: jax.Array,
         raise ValueError(
             "backend 'float' has no mesh execution: float reductions are "
             "not order-exact, so a sharded run could not stay bit-identical")
+    _refuse_recurrent(program, backend, use_sparse=use_sparse, mesh=mesh)
     if backend == "float":
-        vs, spikes = _float_step(program, list(state.vs), frame)
+        vs, spikes, ss = _float_step(program, list(state.vs), frame,
+                                     state.spikes)
         v_out = vs[-1]
-        return (StreamState(vs=tuple(vs), t=state.t + 1),
+        return (StreamState(vs=tuple(vs), t=state.t + 1, spikes=ss),
                 StreamOut(v_out=v_out, logits=program.logits(v_out),
                           rasters=list(spikes) if emit_rasters else None))
     use_pallas = backend in ("pallas", "pallas_sparse", "pallas_events")
@@ -1043,12 +1119,13 @@ def stream_step(program: SNNProgram, state: StreamState, frame: jax.Array,
         mesh=mesh)
     last = conv_maps[-1] if conv_maps else cur
     flat = last.reshape(*last.shape[:2], -1) if last.ndim > 3 else last
-    rasters_fc, v_stack, skips = _run_fc_stack(
+    rasters_fc, v_stack, skips, s_stack = _run_fc_stack(
         program, flat, use_pallas=use_pallas, use_sparse=use_sparse,
         gate_granularity=gate_granularity, use_events=use_events,
         block_b=block_b, interpret=interpret, emit_rasters=emit_rasters,
         event_crossover=event_crossover,
-        v_init=list(state.vs[1 + n_convs:]), mesh=mesh)
+        v_init=list(state.vs[1 + n_convs:]), mesh=mesh,
+        s_init=list(state.spikes))
     new_vs = ((v_enc,) + tuple(v_convs)
               + tuple(jnp.asarray(v) for v in v_stack))
     rasters = None
@@ -1056,7 +1133,7 @@ def stream_step(program: SNNProgram, state: StreamState, frame: jax.Array,
         rasters = ([spikes_enc] + [m[0] for m in conv_maps]
                    + [jnp.asarray(r)[0] for r in rasters_fc])
     v_out = jnp.asarray(v_stack[-1])
-    return (StreamState(vs=new_vs, t=state.t + 1),
+    return (StreamState(vs=new_vs, t=state.t + 1, spikes=tuple(s_stack)),
             StreamOut(v_out=v_out, logits=program.logits(v_out),
                       rasters=rasters, skips=skips,
                       conv_skips=conv_skips if conv_skips else None))
@@ -1125,6 +1202,7 @@ def stream_megastep(program: SNNProgram, state: StreamState,
         raise ValueError(
             "backend 'float' has no mesh execution: float reductions are "
             "not order-exact, so a sharded run could not stay bit-identical")
+    _refuse_recurrent(program, backend, use_sparse=use_sparse, mesh=mesh)
     frames = jnp.asarray(frames)
     if frames.ndim < 3:
         raise ValueError(
@@ -1146,16 +1224,16 @@ def stream_megastep(program: SNNProgram, state: StreamState,
         # eager K-loop, NOT lax.scan: the float (QAT) readout matmul can
         # drift a last ulp when XLA refuses the eager ops under scan, and
         # the contract here is bit-identity with K stream_step ticks
-        vs, v_traj, spk = list(state.vs), [], []
+        vs, ss, v_traj, spk = list(state.vs), state.spikes, [], []
         for t in range(k):
-            vs, spikes = _float_step(program, vs, frames[t])
+            vs, spikes, ss = _float_step(program, vs, frames[t], ss)
             v_traj.append(vs[-1])
             if emit_rasters:
                 spk.append(spikes)
         v_traj = jnp.stack(v_traj)
         rasters = ([jnp.stack([s[i] for s in spk])
                     for i in range(len(spk[0]))] if emit_rasters else None)
-        return (StreamState(vs=tuple(vs), t=state.t + k),
+        return (StreamState(vs=tuple(vs), t=state.t + k, spikes=ss),
                 MegastepOut(v_out=vs[-1], logits=program.logits(vs[-1]),
                             v_out_traj=v_traj,
                             logits_traj=program.logits(v_traj),
@@ -1183,12 +1261,13 @@ def stream_megastep(program: SNNProgram, state: StreamState,
         mesh=mesh)
     last = conv_maps[-1] if conv_maps else spikes_enc
     flat = last.reshape(*last.shape[:2], -1) if last.ndim > 3 else last
-    rasters_fc, v_stack, skips = _run_fc_stack(
+    rasters_fc, v_stack, skips, s_stack = _run_fc_stack(
         program, flat, use_pallas=use_pallas, use_sparse=use_sparse,
         gate_granularity=gate_granularity, use_events=use_events,
         block_b=block_b, interpret=interpret, emit_rasters=True,
         event_crossover=event_crossover,
-        v_init=list(state.vs[1 + n_convs:]), mesh=mesh)
+        v_init=list(state.vs[1 + n_convs:]), mesh=mesh,
+        s_init=list(state.spikes))
     new_vs = ((v_enc,) + tuple(v_convs)
               + tuple(jnp.asarray(v) for v in v_stack))
     # exact per-tick readout trajectory (see docstring): the readout input
@@ -1204,7 +1283,7 @@ def stream_megastep(program: SNNProgram, state: StreamState,
         rasters = ([spikes_enc] + list(conv_maps)
                    + [jnp.asarray(r) for r in rasters_fc])
     v_out = jnp.asarray(v_stack[-1])
-    return (StreamState(vs=new_vs, t=state.t + k),
+    return (StreamState(vs=new_vs, t=state.t + k, spikes=tuple(s_stack)),
             MegastepOut(v_out=v_out, logits=program.logits(v_out),
                         v_out_traj=v_traj,
                         logits_traj=program.logits(v_traj),
@@ -1214,7 +1293,7 @@ def stream_megastep(program: SNNProgram, state: StreamState,
 
 
 def _bitmacro_layer(inp: np.ndarray, wq: np.ndarray, threshold: int,
-                    leak: int, neuron: str):
+                    leak: int, neuron: str, recurrent: bool = False):
     """Execute one spiking layer, (T, F, n_in) bool input frames ->
     ((T, F, n_out) int8 spikes, (F, n_out) final V, InstrCount), on a bank
     of bit-level macros — the distributed multi-macro architecture:
@@ -1229,7 +1308,10 @@ def _bitmacro_layer(inp: np.ndarray, wq: np.ndarray, threshold: int,
         before the neuron-update sequence runs there. Wrap arithmetic
         makes the split exact: mod-2^11 addition composes, so reduced
         per-tile partials equal the single-accumulate word semantics bit
-        for bit (the reason saturate mode is word-level-only, macro.py).
+        for bit (the reason saturate mode is word-level-only, macro.py);
+      * a ``recurrent`` layer's ``wq`` carries n_out further W_MEM rows
+        after its n_in input rows: each frame's previous-tick spikes issue
+        event-driven AccW2V on those rows (zero at t = 0).
 
     Executed cycles equal `isa.count_layer_instructions` on the input
     raster exactly: 2 AccW2V per event per col tile, 2(row_tiles-1) AccV2V
@@ -1238,6 +1320,10 @@ def _bitmacro_layer(inp: np.ndarray, wq: np.ndarray, threshold: int,
     from repro.core.macro import BitMacro
     t_total, n_frames, n_in = inp.shape
     n_out = wq.shape[1]
+    if recurrent:      # the W_rec rows are driven by the layer's own raster
+        inp = np.concatenate([inp, np.zeros((t_total, n_frames, n_out),
+                                            bool)], axis=-1)
+        n_in += n_out
     tiling = mapping.fc_tiling(n_in, n_out)
     wq_tiles = mapping.tile_weights(np.asarray(wq))
     n_banks = -(-n_frames // isa.N_NEURON_SETS)
@@ -1248,6 +1334,8 @@ def _bitmacro_layer(inp: np.ndarray, wq: np.ndarray, threshold: int,
              for _ in range(n_banks)]
     out = np.zeros((t_total, n_frames, n_out), np.int8)
     for t in range(t_total):
+        if recurrent and t > 0:
+            inp[t, :, n_in - n_out:] = out[t - 1].astype(bool)
         for f in range(n_frames):
             bank, set_idx = divmod(f, isa.N_NEURON_SETS)
             grid = banks[bank]
@@ -1283,7 +1371,9 @@ def run_bitmacro(program: SNNProgram, xs: jax.Array) -> NetResult:
     whose partial sums reduce with word-level AccV2V cycles; conv layers
     lower via im2col onto the same grid (one neuron set per (example,
     output position)); frames beyond 13 neuron sets claim extra macro
-    banks. The one remaining constraint is the silicon's two's-complement
+    banks; a recurrent layer's W_rec block is its further W_MEM rows, driven
+    by the layer's previous-tick spikes. The one remaining constraint is
+    the silicon's two's-complement
     *wrap* arithmetic (saturation is a word-level deployment policy, not
     silicon — and the only mode in which split partial sums compose
     exactly; compile with clamp_mode='wrap', see macro.py)."""
@@ -1310,7 +1400,8 @@ def run_bitmacro(program: SNNProgram, xs: jax.Array) -> NetResult:
             inp = cur.reshape(t_total, -1, spec.n_in).astype(bool)
             wq = np.asarray(spec.w)
         out, v, counts = _bitmacro_layer(inp, wq, int(spec.threshold),
-                                         int(spec.leak), program.neuron)
+                                         int(spec.leak), program.neuron,
+                                         recurrent=spec.recurrent)
         total += counts
         if spec.kind == "conv":
             cur = out.reshape(t_total, batch, *out_hw, spec.n_out)
@@ -1347,7 +1438,14 @@ class SparsityReport:
     ``collect_sums`` aggregates (`sparsity_report_from_sums`, raster-free —
     the training-loop-friendly path). Both feed
     `count_network_instructions(program, report=...)` and
-    `energy.measured_edp*`."""
+    `energy.measured_edp*`.
+
+    A recurrent layer's W_rec rows are counted apart: ``n_rec[i]`` is its
+    recurrent row count (its n_out; 0 for a feed-forward layer) and
+    ``rec_events[i]`` the events on those rows — its own spikes of the
+    previous tick, so every spike but the last tick's. The instruction
+    counts cover fan-in ``n_in + n_rec`` (`recurrent_acc_w2v` isolates the
+    W_rec AccW2V cycles); the sparsity properties stay over input rows."""
     n_in: tuple                   # fan-in per macro-stack layer
     n_out: tuple
     neurons: tuple                # per-layer update kind ("rmp"... | "none")
@@ -1366,6 +1464,15 @@ class SparsityReport:
                                           # per input row over all frames —
                                           # the per-row event columns the
                                           # ref_events backend also reports
+    n_rec: Optional[tuple] = None         # per layer: W_rec rows (None:
+                                          # feed-forward program)
+    rec_events: Optional[tuple] = None    # per layer: events on W_rec rows
+
+    @property
+    def _rec(self) -> tuple:
+        """(n_rec, rec_events) per layer, zeros when feed-forward."""
+        zeros = tuple(0 for _ in self.n_in)
+        return (self.n_rec or zeros, self.rec_events or zeros)
 
     @property
     def frames_by_layer(self) -> tuple:
@@ -1466,11 +1573,21 @@ class SparsityReport:
         rasters directly: both route through
         isa.count_layer_instructions_from_events)."""
         counts = isa.InstrCount()
-        for ni, no, neuron, ev, f in zip(self.n_in, self.n_out, self.neurons,
-                                         self.events, self.frames_by_layer):
+        for ni, no, neuron, ev, f, nr, re in zip(
+                self.n_in, self.n_out, self.neurons, self.events,
+                self.frames_by_layer, *self._rec):
             counts += isa.count_layer_instructions_from_events(
-                ev, f, ni, no, neuron)
+                ev + re, f, ni + nr, no, neuron)
         return counts
+
+    @property
+    def recurrent_acc_w2v(self) -> tuple:
+        """Per layer: the AccW2V cycles issued on W_rec rows (2 per
+        recurrent event per column tile; 0 for a feed-forward layer) —
+        a part of `instruction_counts`' acc_w2v."""
+        return tuple(
+            2 * re * mapping.fc_tiling(ni + nr, no).col_tiles
+            for ni, no, nr, re in zip(self.n_in, self.n_out, *self._rec))
 
     def skipped_instruction_counts(self) -> isa.InstrCount:
         """Instruction cycles event-driven execution never issued: the
@@ -1478,10 +1595,10 @@ class SparsityReport:
         row-granular skip model behind the Fig. 11b curve (executed +
         skipped == the dense tally at sparsity 0)."""
         counts = isa.InstrCount()
-        for ni, no, ev, f in zip(self.n_in, self.n_out, self.events,
-                                 self.frames_by_layer):
+        for ni, no, ev, f, nr, re in zip(self.n_in, self.n_out, self.events,
+                                         self.frames_by_layer, *self._rec):
             counts += isa.count_skipped_instructions_from_events(
-                ev, f, ni, no)
+                ev + re, f, ni + nr, no)
         return counts
 
 
@@ -1490,6 +1607,28 @@ def _report_geometry(program: SNNProgram) -> tuple:
     return (tuple(ly.n_in for ly in stack), tuple(ly.n_out for ly in stack),
             tuple("none" if ly.kind == "readout" else program.neuron
                   for ly in stack))
+
+
+def _report_n_rec(program: SNNProgram) -> Optional[tuple]:
+    """W_rec row count per macro-stack layer (None: feed-forward)."""
+    if not program.recurrent_layers:
+        return None
+    return tuple(ly.n_out if ly.recurrent else 0 for ly in program.macro_stack)
+
+
+def _recurrent_rasters(program: SNNProgram, rs: list) -> list:
+    """Per macro-stack layer, the raster its W_rec rows see — its own
+    output raster (the next layer's input, ``rs[i + 1]``) one tick late,
+    zero at t = 0 — or None for a feed-forward layer. ``rs`` are the
+    (T, frames, n) rasters of `_stack_input_rasters`."""
+    out = []
+    for i, spec in enumerate(program.macro_stack):
+        if not spec.recurrent:
+            out.append(None)
+            continue
+        own = rs[i + 1]
+        out.append(np.concatenate([np.zeros_like(own[:1]), own[:-1]]))
+    return out
 
 
 def _stack_input_rasters(program: SNNProgram, rasters: list) -> list:
@@ -1527,13 +1666,18 @@ def sparsity_report(program: SNNProgram, rasters: list) -> SparsityReport:
     rs = _stack_input_rasters(program, rasters)
     T = rs[0].shape[0]
     B = int(np.asarray(rasters[-1]).shape[1])     # fc rasters carry batch
+    n_rec = _report_n_rec(program)
     return SparsityReport(
         n_in=n_in, n_out=n_out, neurons=neurons,
         events=tuple(int(r.sum()) for r in rs),
         frames=T * B, timesteps=T, batch=B,
         occupancy_t=tuple(r.mean(axis=(1, 2)) for r in rs),
         layer_frames=tuple(T * r.shape[1] for r in rs),
-        row_events=tuple(r.astype(np.int64).sum(axis=(0, 1)) for r in rs))
+        row_events=tuple(r.astype(np.int64).sum(axis=(0, 1)) for r in rs),
+        n_rec=n_rec,
+        rec_events=None if n_rec is None else tuple(
+            0 if r is None else int(r.astype(np.int64).sum())
+            for r in _recurrent_rasters(program, rs)))
 
 
 def sparsity_report_from_sums(program: SNNProgram, spike_sums: list,
@@ -1545,6 +1689,12 @@ def sparsity_report_from_sums(program: SNNProgram, spike_sums: list,
     layers see each input pixel once per covering patch; im2col is linear,
     so the patch event total is ``im2col(sum map).sum()`` — exact. Per-
     timestep occupancy is not recoverable from sums (occupancy_t=None)."""
+    if program.recurrent_layers:
+        raise ValueError(
+            "sparsity_report_from_sums: a recurrent layer's W_rec events "
+            "are its spikes of every tick but the last, which per-neuron "
+            "totals cannot separate; build the report from rasters "
+            "(sparsity_report)")
     n_in, n_out, neurons = _report_geometry(program)
     stack = program.macro_stack
     sums = spike_sums[-len(stack):]
@@ -1593,9 +1743,12 @@ def count_network_instructions(program: SNNProgram, rasters: list = None, *,
                          "backend with emit_rasters=True, accounting mode) "
                          "or a SparsityReport")
     counts = isa.InstrCount()
-    for spec, r in zip(program.macro_stack,
-                       _stack_input_rasters(program, rasters)):
+    rs = _stack_input_rasters(program, rasters)
+    for spec, r, rec in zip(program.macro_stack, rs,
+                            _recurrent_rasters(program, rs)):
+        if rec is not None:               # AccW2V over [input; W_rec] rows
+            r = np.concatenate([r, rec], axis=-1)
         counts += isa.count_layer_instructions(
-            r, spec.n_in, spec.n_out,
+            r, spec.fan_in, spec.n_out,
             "none" if spec.kind == "readout" else program.neuron)
     return counts

@@ -34,8 +34,12 @@ def init_fc_snn(key: jax.Array, cfg: SNNModelConfig) -> dict:
     sizes = cfg.layer_sizes
     keys = jax.random.split(key, len(sizes) - 1)
     layers = []
-    for k, (n_in, n_out) in zip(keys, zip(sizes[:-1], sizes[1:])):
-        w = jax.random.normal(k, (n_in, n_out), jnp.float32) * (2.0 / np.sqrt(n_in))
+    for j, (k, (n_in, n_out)) in enumerate(zip(keys, zip(sizes[:-1],
+                                                         sizes[1:]))):
+        # a recurrent layer's block is [W_in; W_rec]: n_out further rows
+        fan_in = n_in + n_out if cfg.is_recurrent(j) else n_in
+        w = jax.random.normal(k, (fan_in, n_out), jnp.float32) * (
+            2.0 / np.sqrt(fan_in))
         layers.append({"w": w})
     n_spiking = len(sizes) - 2                    # last layer is accumulate-only
     return {

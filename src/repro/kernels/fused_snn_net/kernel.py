@@ -57,6 +57,17 @@ exact, chunked calls that thread V compose bit-identically with one full-T
 call — the macro's "V_MEM never leaves the array" claim, restated at the
 call boundary as "V leaves VMEM only between ticks".
 
+Recurrent layers (``recurrent``): a recurrent layer's previous-tick spike
+raster is a second kind of carried state. It lives in its own int32 VMEM
+scratch tile across the timestep loop, seeded from ``s_init`` and written
+back out at the end, exactly as V is. Its weight tile is [W_in; W_rec]
+with W_rec on its own lane-tile rows, and the layer's AccW2V is the
+input-rows matmul plus the recurrent-rows matmul, both int32, summed
+before the one clamp: ``V += [s_in(t), s(t-1)] @ [W_in; W_rec]``, then
+SpikeCheck and the reset, then ``s(t)`` overwrites the scratch. Every
+recurrent branch is chosen in Python at trace time, so a feed-forward
+stack traces to the same kernel as before.
+
 Event-list mode (``events=True``) is the fully event-driven execution the
 gated modes approximate: instead of predicating dense matmuls on tile /
 row-block occupancy, each (timestep, layer, example) int8 spike frame is
@@ -155,24 +166,29 @@ def _net_kernel(*refs, n_spiking: int, has_readout: bool, neuron: str,
                 clamp_mode: str, timesteps: int, emit_rasters: bool,
                 sparse: bool, granularity: int, logical_widths: tuple,
                 batch_logical: int, block_b: int, has_v_init: bool,
-                events: bool = False, dense_thresholds: tuple = ()):
+                events: bool = False, dense_thresholds: tuple = (),
+                recurrent: tuple = ()):
     """Ref layout (inputs, outputs, scratch):
       inputs : spikes_ref (T, Bt, N0p) int8; w_refs[i] (Nip, Nop) int8 for
                the n_spiking FCs (+ readout when has_readout); params_ref
                (n_spiking, 2) int32 rows of [threshold, leak];
                v_init_refs[i] (Bt, Nop) int32 per layer (only when
                has_v_init) — membrane state carried in from a previous
-               streaming tick;
+               streaming tick; s_init_refs[k] (Bt, Nop) int32 per
+               recurrent layer k — its previous-tick spikes;
       outputs: raster_refs[i] (T, Bt, Nop) int8 per spiking FC (only when
                emit_rasters); v_out_refs[i] (Bt, Nop) int32 per layer
-               (readout last); skip_ref (1, skip_lanes) int32 (only when
+               (readout last); s_out_refs[k] (Bt, Nop) int32 per
+               recurrent layer; skip_ref (1, skip_lanes) int32 (only when
                sparse) — gate site (layer i, block g) counts skipped
                matmuls in column skip_layout offsets[i] + g; in events
                mode instead row_refs[i] (1, Nip) int32 per layer — this
                tile's per-input-row event counts — then fallback_ref
                (1, LANE) int32, column i counting the timesteps layer i
                took the dense-crossover fallback;
-      scratch: v_refs[i] (Bt, Nop) int32 per layer — the fused V_MEM tiles.
+      scratch: v_refs[i] (Bt, Nop) int32 per layer — the fused V_MEM
+               tiles; then s_refs[k] (Bt, Nop) int32 per recurrent layer —
+               its last spike raster.
 
     ``has_readout=False`` runs an all-spiking stack (no accumulate-only
     tail) — the shape conv layers lowered onto im2col patch rasters take.
@@ -181,27 +197,43 @@ def _net_kernel(*refs, n_spiking: int, has_readout: bool, neuron: str,
     which the dense fallback fires.
     """
     n_w = n_spiking + (1 if has_readout else 0)
+    # recurrent spiking layer i -> its slot k in the s_* refs
+    rec_slot = {i: k for k, i in
+                enumerate(i for i, r in enumerate(recurrent) if r)}
+    n_rec = len(rec_slot)
     spikes_ref = refs[0]
     w_refs = refs[1:1 + n_w]
     params_ref = refs[1 + n_w]
     pos = 2 + n_w
     v_init_refs = refs[pos:pos + n_w] if has_v_init else ()
     pos += n_w if has_v_init else 0
+    s_init_refs = refs[pos:pos + n_rec]
+    pos += n_rec
     raster_refs = refs[pos:pos + n_spiking] if emit_rasters else ()
     pos += n_spiking if emit_rasters else 0
     v_out_refs = refs[pos:pos + n_w]
     pos += n_w
+    s_out_refs = refs[pos:pos + n_rec]
+    pos += n_rec
     skip_ref = refs[pos] if sparse else None
     pos += 1 if sparse else 0
     row_refs = refs[pos:pos + n_w] if events else ()
     pos += n_w if events else 0
     fallback_ref = refs[pos] if events else None
     pos += 1 if events else 0
-    v_refs = refs[pos:]
+    v_refs = refs[pos:pos + n_w]
+    s_refs = refs[pos + n_w:]
 
-    ws = [w_refs[i][...] for i in range(n_w)]     # VMEM-resident weights
+    if n_rec:
+        # a recurrent stack is wide (W_rec alone is n_out^2): each matmul
+        # reads its weight rows from the VMEM-resident ref where it runs
+        ws = None
+    else:
+        ws = [w_refs[i][...] for i in range(n_w)]  # VMEM-resident weights
     for i, vref in enumerate(v_refs):
         vref[...] = v_init_refs[i][...] if has_v_init else jnp.zeros_like(vref)
+    for k, sref in enumerate(s_refs):
+        sref[...] = s_init_refs[k][...]
     if sparse or events:
         b0 = pl.program_id(0) * block_b
     if sparse:
@@ -311,8 +343,20 @@ def _net_kernel(*refs, n_spiking: int, has_readout: bool, neuron: str,
         if events:
             return accumulate_events(i, cur)
         if not sparse:
-            acc = jax.lax.dot_general(cur, ws[i], (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.int32)
+            if i in rec_slot:            # [s_in(t), s(t-1)] @ [W_in; W_rec]
+                n_in_p = w_refs[i].shape[0] - w_refs[i].shape[1]
+                s_prev = s_refs[rec_slot[i]][...].astype(jnp.int8)
+                acc = (jax.lax.dot_general(
+                    cur, w_refs[i][:n_in_p, :], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.int32)
+                    + jax.lax.dot_general(
+                        s_prev, w_refs[i][n_in_p:, :],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.int32))
+            else:
+                w = ws[i] if ws is not None else w_refs[i][...]
+                acc = jax.lax.dot_general(cur, w, (((1,), (0,)), ((), ())),
+                                          preferred_element_type=jnp.int32)
             v = v_refs[i][...] + acc
             return clamp_v(v, clamp_mode) if i < n_spiking else v
         bw = ws[i].shape[0] if granularity == 1 else LANE // granularity
@@ -355,6 +399,8 @@ def _net_kernel(*refs, n_spiking: int, has_readout: bool, neuron: str,
             else:                                              # ResetV
                 v = jnp.where(fired, 0, v)
             v_refs[i][...] = v
+            if i in rec_slot:                                  # s(t) carried
+                s_refs[rec_slot[i]][...] = fired.astype(jnp.int32)
             cur = fired.astype(jnp.int8)                       # stays in VMEM
             if sparse or events:
                 cur = mask_pad(cur, logical_widths[i + 1])
@@ -370,6 +416,8 @@ def _net_kernel(*refs, n_spiking: int, has_readout: bool, neuron: str,
     jax.lax.fori_loop(0, timesteps, body, 0)
     for i in range(n_w):
         v_out_refs[i][...] = v_refs[i][...]
+    for k in range(n_rec):
+        s_out_refs[k][...] = s_refs[k][...]
 
 
 def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
@@ -379,7 +427,8 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
                          logical_widths: tuple = (),
                          batch_logical: int = 0, has_readout: bool = True,
                          v_init: list = None, events: bool = False,
-                         event_crossover: float = 1.0):
+                         event_crossover: float = 1.0, recurrent: tuple = (),
+                         s_init: list = None):
     """Dispatch the network kernel. Shapes must be pre-padded: spikes
     (T, B, N0p) int8 with B % block_b == 0; ws[i] (Nip, Nop) int8 with every
     dim a 128 multiple and Nip == previous Nop; params (n_spiking, 2) int32.
@@ -397,6 +446,11 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
     pre-padded like ws, seeding the VMEM V scratch instead of zeros — the
     carried state of a `stream_step` tick.
 
+    ``recurrent`` (one flag per spiking layer) marks the layers whose
+    ws[i] is the padded [W_in; W_rec] block (Nip + Nop, Nop); ``s_init``
+    then holds one (B, Nop) int32 {0,1} raster per recurrent layer, its
+    previous-tick spikes (module docs).
+
     ``events`` selects the compacted event-list execution of AccW2V (module
     docs) — mutually exclusive with ``sparse``; needs the same
     ``logical_widths`` / ``batch_logical`` masking inputs. A tile whose
@@ -412,7 +466,8 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
     in events mode the pair (row_counts, fallbacks) with row_counts[i]
     (B // block_b, Nip) int32 per-input-row event counts per tile and
     fallbacks (B // block_b, len(ws)) int32 dense-fallback trip counts;
-    None otherwise.
+    None otherwise. With a recurrent layer a fourth element follows: the
+    final (B, Nop) int32 raster of each recurrent layer.
     """
     T, B, _ = spikes.shape
     n_spiking = len(ws) - 1 if has_readout else len(ws)
@@ -438,6 +493,12 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
             int(event_crossover * block_b * logical_widths[i]) if
             event_crossover > 0.0 else -1
             for i in range(len(ws)))
+    n_rec = sum(bool(r) for r in recurrent)
+    if n_rec and (sparse or events):
+        raise ValueError("recurrent layers run on the dense kernel only")
+    if n_rec and (s_init is None or len(s_init) != n_rec):
+        raise ValueError(f"s_init needs one (B, Nop) raster per recurrent "
+                         f"layer ({n_rec})")
     kernel = functools.partial(
         _net_kernel, n_spiking=n_spiking, has_readout=has_readout,
         neuron=neuron, clamp_mode=clamp_mode, timesteps=T,
@@ -445,7 +506,7 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
         logical_widths=tuple(logical_widths),
         batch_logical=batch_logical, block_b=block_b,
         has_v_init=v_init is not None, events=events,
-        dense_thresholds=dense_thresholds)
+        dense_thresholds=dense_thresholds, recurrent=tuple(recurrent))
 
     in_specs = [pl.BlockSpec((T, block_b, spikes.shape[2]),
                              lambda b: (0, b, 0))]
@@ -457,6 +518,9 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
                              f"({len(ws)}), got {len(v_init)}")
         in_specs += [pl.BlockSpec((block_b, w.shape[1]), lambda b: (b, 0))
                      for w in ws]
+    rec_ws = [w for w, r in zip(ws, recurrent) if r]
+    in_specs += [pl.BlockSpec((block_b, w.shape[1]), lambda b: (b, 0))
+                 for w in rec_ws]
 
     out_specs, out_shape = [], []
     if emit_rasters:
@@ -464,7 +528,7 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
             out_specs.append(pl.BlockSpec((T, block_b, w.shape[1]),
                                           lambda b: (0, b, 0)))
             out_shape.append(jax.ShapeDtypeStruct((T, B, w.shape[1]), jnp.int8))
-    for w in ws:
+    for w in ws + rec_ws:            # V per layer, then recurrent rasters
         out_specs.append(pl.BlockSpec((block_b, w.shape[1]), lambda b: (b, 0)))
         out_shape.append(jax.ShapeDtypeStruct((B, w.shape[1]), jnp.int32))
     # per-tile counter rows: (tiles, 1, X) arrays in (1, X) blocks (the
@@ -484,7 +548,8 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
             counter(w.shape[0])
         counter(LANE)
 
-    scratch = [pltpu.VMEM((block_b, w.shape[1]), jnp.int32) for w in ws]
+    scratch = [pltpu.VMEM((block_b, w.shape[1]), jnp.int32)
+               for w in ws + rec_ws]
 
     # ``name`` fixes the custom call's HLO instruction name, so the
     # kernel's trace events read ``fused_snn_net.N`` under any enclosing jit
@@ -497,7 +562,8 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
         scratch_shapes=scratch,
         interpret=interpret,
         name="fused_snn_net",
-    )(spikes, *ws, params, *(v_init if v_init is not None else ()))
+    )(spikes, *ws, params, *(v_init if v_init is not None else ()),
+      *(s_init if n_rec else ()))
     outs = list(outs)
     skips = None
     if sparse:
@@ -507,6 +573,9 @@ def fused_snn_net_pallas(spikes: jax.Array, ws: list, params: jax.Array, *,
         row_counts = [rc[:, 0] for rc in outs[-len(ws):]]
         del outs[-len(ws):]
         skips = (row_counts, fallbacks)
-    rasters = outs[:n_spiking] if emit_rasters else []
-    v_finals = outs[n_spiking:] if emit_rasters else outs
+    n_r = n_spiking if emit_rasters else 0
+    rasters = outs[:n_r]
+    v_finals = outs[n_r:n_r + len(ws)]
+    if n_rec:
+        return rasters, v_finals, skips, outs[n_r + len(ws):]
     return rasters, v_finals, skips

@@ -19,6 +19,17 @@ Pallas kernel and the pure-jnp reference implement the gate (`@pl.when` /
 layer: a (tiles, n_layers) array at granularity 1, a per-layer list of
 (tiles, n_blocks_i) arrays at finer granularities (block counts vary with
 each layer's fan-in — `kernel.skip_layout`).
+
+Recurrent layers (``recurrent``, one flag per spiking layer): such a
+layer's weight block is [W_in; W_rec], (n_in + n_out, n_out) — its
+previous-tick spikes drive n_out further W_MEM rows after the input rows,
+on the same 6-bit grid. Its last spike raster is carried state beside V:
+``s_init`` seeds it (zeros: s(-1) = 0) and the call returns it, so chunked
+calls that thread both compose bit-identically with one whole call. The
+padded block is [pad(W_in); pad(W_rec)], each part padded to whole lane
+tiles, so the kernel's two AccW2V matmuls read aligned row blocks. Only
+the dense kernel and its reference take recurrent layers; the gated and
+event modes refuse them.
 """
 from __future__ import annotations
 
@@ -52,10 +63,12 @@ def _pad_axis(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def _check_stack(spikes: jax.Array, ws: list) -> None:
+def _check_stack(spikes: jax.Array, ws: list, recurrent: tuple = ()
+                 ) -> None:
     """Chain-alignment on LOGICAL widths (padded widths can coincide for
-    mismatched stacks): layer i's fan-in == layer i-1's fan-out. Raises
-    (rather than asserts) so the contract survives ``python -O``."""
+    mismatched stacks): layer i's fan-in == layer i-1's fan-out, plus its
+    own n_out recurrent rows when ``recurrent[i]``. Raises (rather than
+    asserts) so the contract survives ``python -O``."""
     if not ws:
         raise ValueError("fused_snn_net needs a non-empty weight stack "
                          "(spiking FCs first, readout last); got ws=[]")
@@ -64,18 +77,53 @@ def _check_stack(spikes: jax.Array, ws: list) -> None:
         if w.ndim != 2:
             raise ValueError(f"ws[{i}] must be a 2-D (n_in, n_out) weight "
                              f"matrix, got shape {w.shape}")
-        if w.shape[0] != prev:
+        rec = w.shape[1] if i < len(recurrent) and recurrent[i] else 0
+        if w.shape[0] != prev + rec:
             raise ValueError(
                 f"layer chain misaligned: ws[{i}] has fan-in {w.shape[0]} "
-                f"but the previous layer emits {prev} lanes")
+                f"but the previous layer emits {prev} lanes"
+                + (f" (+ {rec} recurrent rows)" if rec else ""))
         prev = w.shape[1]
+
+
+def _check_recurrent(recurrent: tuple, n_spiking: int, use_sparse: bool,
+                     use_events: bool, s_init) -> int:
+    """Validate the recurrent flags of one call; returns the number of
+    recurrent layers (the length ``s_init`` must have)."""
+    if recurrent and len(recurrent) != n_spiking:
+        raise ValueError(f"recurrent needs one flag per spiking layer "
+                         f"({n_spiking}), got {len(recurrent)}")
+    n_rec = sum(recurrent)
+    if n_rec and (use_sparse or use_events):
+        raise ValueError("recurrent layers run on the dense kernel and its "
+                         "reference only; the gated (use_sparse) and event "
+                         "(use_events) modes do not take them")
+    if not n_rec and s_init is not None:
+        raise ValueError("s_init carries recurrent spike state; this stack "
+                         "has no recurrent layer")
+    if n_rec and s_init is not None and len(s_init) != n_rec:
+        raise ValueError(f"s_init needs one (B, n_out) raster per recurrent "
+                         f"layer ({n_rec}), got {len(s_init)}")
+    return n_rec
+
+
+def _pad_weight(w: jax.Array, recurrent: bool) -> jax.Array:
+    """Lane-pad one weight block; a recurrent [W_in; W_rec] block pads
+    each part on its own, so W_rec starts on a lane-tile row."""
+    def pad(x):
+        return _pad_axis(_pad_axis(x.astype(jnp.int8), 0, LANE), 1, LANE)
+    if not recurrent:
+        return pad(w)
+    n_in = w.shape[0] - w.shape[1]
+    return jnp.concatenate([pad(w[:n_in]), pad(w[n_in:])], axis=0)
 
 
 @partial(jax.jit, static_argnames=("thresholds", "leaks", "neuron",
                                    "clamp_mode", "block_b", "use_pallas",
                                    "interpret", "emit_rasters", "use_sparse",
                                    "gate_granularity", "readout",
-                                   "use_events", "event_crossover"))
+                                   "use_events", "event_crossover",
+                                   "recurrent"))
 def fused_snn_net(spikes: jax.Array, ws: list, *, thresholds: tuple,
                   leaks: tuple, neuron: str = "rmp",
                   clamp_mode: str = "saturate", block_b: int = 8,
@@ -83,7 +131,8 @@ def fused_snn_net(spikes: jax.Array, ws: list, *, thresholds: tuple,
                   emit_rasters: bool = True, use_sparse: bool = False,
                   gate_granularity: int = 1, readout: bool = True,
                   v_init: list = None, use_events: bool = False,
-                  event_crossover: float = 1.0):
+                  event_crossover: float = 1.0, recurrent: tuple = (),
+                  s_init: list = None):
     """Run a (T, B, N0) encoder spike raster through the whole fc stack.
 
     ``ws``: per-layer int8 weights, spiking FCs first, readout last;
@@ -117,9 +166,17 @@ def fused_snn_net(spikes: jax.Array, ws: list, *, thresholds: tuple,
     ``{"row_events": [per-layer (B_tiles, n_in) int32 counts],
     "dense_fallbacks": (B_tiles, n_layers) int32}`` — wrap with
     `fused_snn_net_device_events` to get an `events.EventStats`.
+
+    ``recurrent`` (one flag per spiking layer; module docs) makes layer i
+    recurrent: ``ws[i]`` is then [W_in; W_rec], and ``s_init`` — one
+    (B, n_out) {0,1} raster per recurrent layer, default zeros — is its
+    previous-tick spikes. A call with a recurrent layer returns a fourth
+    element, the final spike raster of each recurrent layer (B, n_out)
+    int8; feed-forward calls return three.
     """
     thresholds, leaks = tuple(thresholds), tuple(leaks)
-    _check_stack(spikes, ws)
+    recurrent = tuple(bool(r) for r in recurrent)
+    _check_stack(spikes, ws, recurrent)
     if v_init is not None and len(v_init) != len(ws):
         raise ValueError(f"v_init needs one (B, n_out) state per layer "
                          f"({len(ws)}), got {len(v_init)}")
@@ -147,14 +204,24 @@ def fused_snn_net(spikes: jax.Array, ws: list, *, thresholds: tuple,
         raise ValueError(
             f"need one threshold/leak per spiking layer ({n_spiking} with "
             f"readout={readout}), got {len(thresholds)}/{len(leaks)}")
+    n_rec = _check_recurrent(recurrent, n_spiking, use_sparse, use_events,
+                             s_init)
+    rec_widths = [w.shape[1] for w, r in zip(ws, recurrent) if r]
+    if n_rec and s_init is None:                 # s(-1) = 0
+        s_init = [jnp.zeros((spikes.shape[1], n), jnp.int8)
+                  for n in rec_widths]
     if not use_pallas:
         return _fused_snn_net_ref(spikes, ws, thresholds, leaks, neuron,
                                   clamp_mode, emit_rasters, use_sparse,
-                                  readout, gate_granularity, v_init)
+                                  readout, gate_granularity, v_init,
+                                  recurrent, s_init)
     T, B, N0 = spikes.shape
     s = _pad_axis(_pad_axis(spikes.astype(jnp.int8), 2, LANE), 1, block_b)
-    ws_p = [_pad_axis(_pad_axis(w.astype(jnp.int8), 0, LANE), 1, LANE)
-            for w in ws]
+    ws_p = [_pad_weight(w, i < len(recurrent) and recurrent[i])
+            for i, w in enumerate(ws)]
+    # the kernel carries the rasters as int32 words, like V
+    s_init_p = [_pad_axis(_pad_axis(x.astype(jnp.int32), 1, LANE), 0,
+                          block_b) for x in (s_init or ())]
     v_init_p = None
     if v_init is not None:
         # padded batch rows / lanes resume from 0 V, exactly as a
@@ -163,12 +230,14 @@ def fused_snn_net(spikes: jax.Array, ws: list, *, thresholds: tuple,
                               0, block_b) for v in v_init]
     params = jnp.asarray([[t, lk] for t, lk in zip(thresholds, leaks)],
                          jnp.int32).reshape(len(thresholds), 2)
-    rasters, v_finals, skips = fused_snn_net_pallas(
+    outs = fused_snn_net_pallas(
         s, ws_p, params, neuron=neuron, clamp_mode=clamp_mode,
         block_b=block_b, emit_rasters=emit_rasters, interpret=interpret,
         sparse=use_sparse, granularity=gate_granularity, has_readout=readout,
         logical_widths=widths, batch_logical=B, v_init=v_init_p,
-        events=use_events, event_crossover=event_crossover)
+        events=use_events, event_crossover=event_crossover,
+        recurrent=recurrent, s_init=s_init_p or None)
+    rasters, v_finals, skips = outs[:3]
     rasters = [r[:, :B, :w.shape[1]]
                for r, w in zip(rasters, ws[:n_spiking])]
     v_finals = [v[:B, :w.shape[1]] for v, w in zip(v_finals, ws)]
@@ -183,6 +252,9 @@ def fused_snn_net(spikes: jax.Array, ws: list, *, thresholds: tuple,
             split.append(skips[:, off:off + n])
             off += n
         skips = split
+    if n_rec:
+        return rasters, v_finals, skips, [
+            x[:B, :n].astype(jnp.int8) for x, n in zip(outs[3], rec_widths)]
     return rasters, v_finals, skips
 
 
@@ -293,17 +365,21 @@ def mesh_rowpartial_tick(vs, counts, frame, ws_l, *, widths: tuple,
                                    "clamp_mode", "block_b", "use_pallas",
                                    "interpret", "emit_rasters", "use_sparse",
                                    "gate_granularity", "readout",
-                                   "use_events", "event_crossover"))
-def _fused_snn_net_mesh_core(spikes, ws, v_init, *, mesh, thresholds, leaks,
-                             neuron, clamp_mode, block_b, use_pallas,
+                                   "use_events", "event_crossover",
+                                   "recurrent"))
+def _fused_snn_net_mesh_core(spikes, ws, v_init, s_init, *, mesh, thresholds,
+                             leaks, neuron, clamp_mode, block_b, use_pallas,
                              interpret, emit_rasters, use_sparse,
                              gate_granularity, readout, use_events,
-                             event_crossover):
+                             event_crossover, recurrent):
     """The traced mesh body (see `fused_snn_net_mesh` for the contract).
     ``mesh`` is hashable, hence a static argname: the shard_map in/out
     specs are built per (mesh, shapes, flags) trace. ``v_init`` is always
-    a concrete per-layer list here (zeros for a from-scratch run) so the
-    shard_map operand tree is structurally fixed."""
+    a concrete per-layer list here (zeros for a from-scratch run), and
+    ``s_init`` one raster per recurrent layer (empty for a feed-forward
+    stack, which then traces exactly as without it), so the shard_map
+    operand tree is structurally fixed. Returns (rasters, v_finals,
+    skips, s_finals)."""
     from repro.dist.sharding import logical_spec
     n_data, n_model = mesh_axis_extents(mesh)
     T, B, N0 = spikes.shape
@@ -311,6 +387,7 @@ def _fused_snn_net_mesh_core(spikes, ws, v_init, *, mesh, thresholds, leaks,
     n_spiking = len(ws) - 1 if readout else len(ws)
     s = _pad_axis(spikes.astype(jnp.int8), 1, n_data)
     vi = [_pad_axis(v.astype(jnp.int32), 0, n_data) for v in v_init]
+    si = [_pad_axis(x.astype(jnp.int8), 0, n_data) for x in s_init]
 
     if n_model == 1:
         # pure lane (data) parallelism: every shard runs the REAL
@@ -318,20 +395,23 @@ def _fused_snn_net_mesh_core(spikes, ws, v_init, *, mesh, thresholds, leaks,
         # jnp reference — on its contiguous lane slice. Lanes never
         # interact, so per-shard results equal the single-device values
         # bit for bit and reassemble by concatenation.
-        def body(s_l, ws_l, vi_l):
-            r, v, sk = fused_snn_net(
+        def body(s_l, ws_l, vi_l, si_l):
+            r, v, sk, *sf = fused_snn_net(
                 s_l, list(ws_l), thresholds=thresholds, leaks=leaks,
                 neuron=neuron, clamp_mode=clamp_mode, block_b=block_b,
                 use_pallas=use_pallas, interpret=interpret,
                 emit_rasters=emit_rasters, use_sparse=use_sparse,
                 gate_granularity=gate_granularity, readout=readout,
                 v_init=list(vi_l), use_events=use_events,
-                event_crossover=event_crossover)
-            return list(r), list(v), sk
+                event_crossover=event_crossover, recurrent=recurrent,
+                s_init=list(si_l) or None)
+            return list(r), list(v), sk, list(sf[0] if sf else ())
 
         lane_spec = logical_spec(mesh, (None, "lane", None), s.shape,
                                  required=("lane",))
-        in_specs = (lane_spec, [P()] * len(ws), [P("data")] * len(ws))
+        s_spec = [P("data")] * len(si)
+        in_specs = (lane_spec, [P()] * len(ws), [P("data")] * len(ws),
+                    s_spec)
         r_spec = [P(None, "data", None)] * (n_spiking if emit_rasters else 0)
         v_spec = [P("data")] * len(ws)
         if use_events:
@@ -344,12 +424,12 @@ def _fused_snn_net_mesh_core(spikes, ws, v_init, *, mesh, thresholds, leaks,
                        else P("data"))
         else:
             sk_spec = None
-        rasters, v_finals, skips = jax.shard_map(
+        rasters, v_finals, skips, s_finals = jax.shard_map(
             body, mesh=mesh, in_specs=in_specs,
-            out_specs=(r_spec, v_spec, sk_spec),
-            check_vma=False)(s, list(ws), vi)
+            out_specs=(r_spec, v_spec, sk_spec, s_spec),
+            check_vma=False)(s, list(ws), vi, si)
         return ([r[:, :B] for r in rasters], [v[:B] for v in v_finals],
-                skips)
+                skips, [x[:B] for x in s_finals])
 
     # model parallelism: the AccV2V reduction across devices — see
     # `mesh_rowpartial_tick` (the traceable per-frame body). Widths pad to
@@ -394,7 +474,7 @@ def _fused_snn_net_mesh_core(spikes, ws, v_init, *, mesh, thresholds, leaks,
         out_specs=(r_spec, v_spec, c_spec),
         check_vma=False)(s, ws_p, vi)
     return ([r[:, :B] for r in rasters], [v[:B] for v in v_finals],
-            counts if use_events else None)
+            counts if use_events else None, [])
 
 
 def fused_snn_net_mesh(spikes: jax.Array, ws: list, *, mesh,
@@ -404,7 +484,8 @@ def fused_snn_net_mesh(spikes: jax.Array, ws: list, *, mesh,
                        emit_rasters: bool = True, use_sparse: bool = False,
                        gate_granularity: int = 1, readout: bool = True,
                        v_init: list = None, use_events: bool = False,
-                       event_crossover: float = 1.0):
+                       event_crossover: float = 1.0, recurrent: tuple = (),
+                       s_init: list = None):
     """`fused_snn_net` on a `jax.sharding.Mesh` — same stack, same
     results, executed under shard_map. Placement is config-driven through
     `repro.dist.sharding`'s logical axes: "lane" (batch) partitions over
@@ -436,12 +517,17 @@ def fused_snn_net_mesh(spikes: jax.Array, ws: list, *, mesh,
     ``skips`` is an `events.EventStats` folded on the host — do not call
     that combination under an outer jit.
 
+    ``recurrent``/``s_init`` as in `fused_snn_net` (a fourth element, the
+    final rasters, comes back): on a data-only mesh the recurrent rasters
+    shard with the lanes; the model axis' row-partial tick carries no
+    raster state and refuses a recurrent stack.
+
     Raises ValueError on a misaligned stack or invalid flag combination,
     `repro.dist.sharding.ShardingError` if a required axis cannot be
     honoured (cannot happen after padding; defensive).
     """
     thresholds, leaks = tuple(thresholds), tuple(leaks)
-    _check_stack(spikes, ws)
+    _check_stack(spikes, ws, tuple(recurrent))
     if v_init is not None and len(v_init) != len(ws):
         raise ValueError(f"v_init needs one (B, n_out) state per layer "
                          f"({len(ws)}), got {len(v_init)}")
@@ -460,13 +546,26 @@ def fused_snn_net_mesh(spikes: jax.Array, ws: list, *, mesh,
     n_data, n_model = mesh_axis_extents(mesh)
     if v_init is None:
         v_init = [jnp.zeros((B, w.shape[1]), jnp.int32) for w in ws]
-    rasters, v_finals, skips = _fused_snn_net_mesh_core(
-        spikes, list(ws), list(v_init), mesh=mesh, thresholds=thresholds,
-        leaks=leaks, neuron=neuron, clamp_mode=clamp_mode, block_b=block_b,
-        use_pallas=use_pallas, interpret=interpret,
-        emit_rasters=emit_rasters, use_sparse=use_sparse,
-        gate_granularity=gate_granularity, readout=readout,
-        use_events=use_events, event_crossover=event_crossover)
+    recurrent = tuple(bool(r) for r in recurrent)
+    n_rec = _check_recurrent(recurrent, len(ws) - 1 if readout else len(ws),
+                             use_sparse, use_events, s_init)
+    if n_rec and n_model > 1:
+        raise ValueError("recurrent layers: the model axis' row-partial "
+                         "tick carries no raster state; use a mesh whose "
+                         "model extent is 1")
+    if n_rec and s_init is None:                 # s(-1) = 0
+        s_init = [jnp.zeros((B, w.shape[1]), jnp.int8)
+                  for w, r in zip(ws, recurrent) if r]
+    rasters, v_finals, skips, s_finals = _fused_snn_net_mesh_core(
+        spikes, list(ws), list(v_init), list(s_init or ()), mesh=mesh,
+        thresholds=thresholds, leaks=leaks, neuron=neuron,
+        clamp_mode=clamp_mode, block_b=block_b, use_pallas=use_pallas,
+        interpret=interpret, emit_rasters=emit_rasters,
+        use_sparse=use_sparse, gate_granularity=gate_granularity,
+        readout=readout, use_events=use_events,
+        event_crossover=event_crossover, recurrent=recurrent)
+    if n_rec:
+        return rasters, v_finals, skips, s_finals
     if use_events:
         import numpy as np
 
@@ -487,8 +586,12 @@ def fused_snn_net_mesh(spikes: jax.Array, ws: list, *, mesh,
 
 def _fused_snn_net_ref(spikes, ws, thresholds, leaks, neuron, clamp_mode,
                        emit_rasters, use_sparse=False, readout=True,
-                       gate_granularity=1, v_init=None):
-    """Pure-jnp oracle: the word-level ISA scanned over the network. In
+                       gate_granularity=1, v_init=None, recurrent=(),
+                       s_init=None):
+    """Pure-jnp oracle: the word-level ISA scanned over the network (a
+    recurrent layer's own previous-tick spikes ride in the scan carry and
+    drive its W_rec rows, `isa.layer_timestep_int(recurrent_spikes=...)`;
+    the final rasters come back as a fourth element). In
     ``use_sparse`` mode the AccW2V matmul of each lane block (the whole
     layer at granularity 1) is wrapped in a `lax.cond` on whole-batch
     occupancy (the reference's tile = the whole batch) and per-(layer,
@@ -516,13 +619,24 @@ def _fused_snn_net_ref(spikes, ws, thresholds, leaks, neuron, clamp_mode,
         v = clamp_v(v, clamp_mode) if clamp else v
         return v, jnp.stack(skipped)
 
+    rec_slot = {i: k for k, i in
+                enumerate(i for i, r in enumerate(recurrent) if r)}
+
     def step(carry, s_t):
-        vs, skips = list(carry[0]), list(carry[1])
+        vs, skips, ss = list(carry[0]), list(carry[1]), list(carry[2])
         cur = s_t.astype(jnp.int32)
         rasters = []
         skipped = []
         for i, w in enumerate(spiking_ws):
-            if use_sparse:
+            if i in rec_slot:
+                vs[i], cur = layer_timestep_int(
+                    vs[i], w, cur, neuron=neuron,
+                    threshold=jnp.int32(thresholds[i]),
+                    leak=jnp.int32(leaks[i]), reset=jnp.int32(0),
+                    clamp_mode=clamp_mode,
+                    recurrent_spikes=ss[rec_slot[i]])
+                ss[rec_slot[i]] = cur
+            elif use_sparse:
                 v, sk = gated_acc(vs[i], w, cur, blocks[i], clamp=True)
                 skipped.append(sk)
                 vs[i], cur = neuron_dynamics_int(
@@ -545,19 +659,23 @@ def _fused_snn_net_ref(spikes, ws, thresholds, leaks, neuron, clamp_mode,
                 vs[-1] = vs[-1] + cur @ ws[-1].astype(jnp.int32)
         if use_sparse:
             skips = [s + d for s, d in zip(skips, skipped)]
-        return (tuple(vs), tuple(skips)), tuple(rasters)
+        return (tuple(vs), tuple(skips), tuple(ss)), tuple(rasters)
 
     if v_init is not None:
         vs0 = tuple(v.astype(jnp.int32) for v in v_init)
     else:
         vs0 = tuple(jnp.zeros((B, w.shape[1]), jnp.int32) for w in ws)
     skips0 = tuple(jnp.zeros((len(b),), jnp.int32) for b in blocks)
-    (vs, skips), rasters = jax.lax.scan(step, (vs0, skips0),
-                                        spikes.astype(jnp.int8))
+    ss0 = tuple(s.astype(jnp.int32) for s in (s_init or ()))
+    (vs, skips, ss), rasters = jax.lax.scan(step, (vs0, skips0, ss0),
+                                            spikes.astype(jnp.int8))
     if not use_sparse:
         out_skips = None
     elif gate_granularity == 1:        # legacy (1, n_layers) layout
         out_skips = jnp.stack([s[0] for s in skips])[None]
     else:
         out_skips = [s[None] for s in skips]
-    return ((list(rasters) if emit_rasters else []), list(vs), out_skips)
+    out = ((list(rasters) if emit_rasters else []), list(vs), out_skips)
+    if rec_slot:
+        return out + ([s.astype(jnp.int8) for s in ss],)
+    return out

@@ -117,6 +117,10 @@ class _Slot:
     ticks: int = 0
     serial: int = -1                      # admission sequence number
     row_events: list = field(default_factory=list)
+    # recurrent layers: events on their W_rec rows so far, and the spike
+    # count of the last served tick (the W_rec input of the next one)
+    rec_events: list = field(default_factory=list)
+    rec_last: list = field(default_factory=list)
 
 
 class _ArrivalQueue:
@@ -153,10 +157,14 @@ def merge_reports(reports: list) -> SparsityReport:
         raise ValueError("merge_reports needs at least one report")
     head = reports[0]
     for r in reports[1:]:
-        if (r.n_in, r.n_out, r.neurons) != (head.n_in, head.n_out,
-                                            head.neurons):
+        if (r.n_in, r.n_out, r.neurons, r.n_rec) != (
+                head.n_in, head.n_out, head.neurons, head.n_rec):
             raise ValueError("cannot merge reports of different programs")
     return SparsityReport(
+        n_rec=head.n_rec,
+        rec_events=None if head.n_rec is None else tuple(
+            sum(r.rec_events[i] for r in reports)
+            for i in range(len(head.n_in))),
         n_in=head.n_in, n_out=head.n_out, neurons=head.neurons,
         events=tuple(sum(r.events[i] for r in reports)
                      for i in range(len(head.n_in))),
@@ -335,6 +343,7 @@ class SNNServeEngine(SlotEngine):
         self.finished: list[SNNRequest] = []
         self._n_in, self._n_out, self._neurons = \
             pipeline._report_geometry(program)
+        self._n_rec = pipeline._report_n_rec(program)
         # frames each macro-stack layer runs per engine tick and per lane:
         # 1 for FC layers, H_out*W_out output positions for im2col'd convs
         self._lane_frames = tuple(
@@ -445,13 +454,12 @@ class SNNServeEngine(SlotEngine):
                 np.float32 if self.backend == "float" else np.int32)
             req.finish_clock = self.clock
             if self.track_events:   # reports only when accounting
-                req.report = self._finalize_report(_Slot(
-                    req=req, row_events=[np.zeros(n, np.int64)
-                                         for n in self._n_in]))
+                req.report = self._finalize_report(self._fresh_slot(req))
             self.finished.append(req)
             return False
-        # admit-by-lane-copy: the fresh request's (zero) V tree enters the
-        # slot's lane; the V_MEM lane is the KV-cache analogue
+        # admit-by-lane-copy: the fresh request's (zero) V tree, and the
+        # zero spike raster of each recurrent layer, enter the slot's
+        # lane; the V_MEM lane is the KV-cache analogue
         page, lane = divmod(i, self.B)
         self.states[page] = lane_scatter(
             self._fresh, self.states[page], self._batch_axes, lane)
@@ -460,8 +468,17 @@ class SNNServeEngine(SlotEngine):
         slot.ticks = 0
         slot.serial = self._admit_seq
         self._admit_seq += 1
-        slot.row_events = [np.zeros(n, np.int64) for n in self._n_in]
+        fresh = self._fresh_slot(req)
+        slot.row_events = fresh.row_events
+        slot.rec_events, slot.rec_last = fresh.rec_events, fresh.rec_last
         return True
+
+    def _fresh_slot(self, req: SNNRequest) -> _Slot:
+        """A slot for ``req`` with zeroed event tallies (s(-1) = 0)."""
+        zeros = [0] * len(self._n_in)
+        return _Slot(req=req,
+                     row_events=[np.zeros(n, np.int64) for n in self._n_in],
+                     rec_events=list(zeros), rec_last=list(zeros))
 
     # -- per-slot event accounting ------------------------------------------
     def _account(self, rasters: list, served: list) -> None:
@@ -480,6 +497,19 @@ class SNNServeEngine(SlotEngine):
             for i, lane, n in served:
                 self.slots[i].row_events[li] += \
                     counts[:n, lane * p:(lane + 1) * p].sum(axis=(0, 1))
+        # a recurrent layer's W_rec rows see its own output (the next
+        # layer's input) one tick late: the tick before the block, then
+        # every served tick of the block but the last
+        for li in (li for li, ly in enumerate(self.program.macro_stack)
+                   if ly.recurrent):
+            own = rs[li + 1]                  # (K, B, n_out) fc raster
+            for i, lane, n in served:
+                if n == 0:
+                    continue
+                slot = self.slots[i]
+                ticks = own[:n, lane].astype(np.int64).sum(axis=1)
+                slot.rec_events[li] += slot.rec_last[li] + int(ticks[:-1].sum())
+                slot.rec_last[li] = int(ticks[-1])
 
     def _account_device(self, out) -> None:
         """Pool one dispatch's executor-reported `EventStats` (fc stack in
@@ -550,7 +580,9 @@ class SNNServeEngine(SlotEngine):
             events=tuple(int(r.sum()) for r in row_events),
             frames=t, timesteps=t, batch=1,
             layer_frames=tuple(t * p for p in self._lane_frames),
-            row_events=row_events)
+            row_events=row_events, n_rec=self._n_rec,
+            rec_events=(None if self._n_rec is None
+                        else tuple(slot.rec_events)))
 
     # -- frame staging -------------------------------------------------------
     def _block_meta(self, page: int) -> tuple:
@@ -721,8 +753,9 @@ class SNNServeEngine(SlotEngine):
                     req.report = self._finalize_report(slot)
                 self.finished.append(req)
                 # idle lanes are silent: re-seed the vacated lane with
-                # fresh zero state so deeper layers cannot keep
-                # leaking/firing from carried V until re-admission
+                # fresh zero state (V and recurrent rasters) so deeper
+                # layers cannot keep leaking/firing from carried state
+                # until re-admission
                 self.states[page] = lane_scatter(
                     self._fresh, self.states[page], self._batch_axes, lane)
                 self.slots[i] = _Slot()

@@ -97,3 +97,36 @@ def test_kernel_instruction_name_is_stable(one_chip):
     names = [ln.split(" = ", 1)[0].split()[-1] for ln in text.splitlines()
              if "custom-call(" in ln and "tpu_custom_call" in ln]
     assert names == ["%fused_snn_net.1"]
+
+
+#: rsnn-ssc at its full widths: 700 -> 400r -> 400r -> 35
+RSNN_WIDTHS = (700, 400, 400, 35)
+
+
+@pytest.mark.parametrize("carried", [False, True], ids=["batch", "carried"])
+def test_recurrent_kernel_compiles_for_v5e(one_chip, carried):
+    """The dense kernel with two recurrent layers at rsnn-ssc widths, from
+    rest and from carried V and rasters (the streaming entry), compiles
+    to one Mosaic custom call that returns the rasters."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = RSNN_WIDTHS
+    ws = [sds((w[0] + w[1], w[1]), jnp.int8),
+          sds((w[1] + w[2], w[2]), jnp.int8), sds((w[2], w[3]), jnp.int8)]
+    kw = dict(thresholds=(40, 40), leaks=(0, 0), block_b=BLOCK_B,
+              recurrent=(True, True), emit_rasters=False)
+    spikes = sds((T, BATCH, w[0]), jnp.int8)
+    if carried:
+        vs = [sds((BATCH, n), jnp.int32) for n in w[1:]]
+        ss = [sds((BATCH, n), jnp.int8) for n in w[1:3]]
+        fn = jax.jit(lambda s, ws, v, r: fused_snn_net(
+            s, ws, v_init=v, s_init=r, **kw))
+        compiled = fn.lower(spikes, ws, vs, ss).compile()
+    else:
+        fn = jax.jit(lambda s, ws: fused_snn_net(s, ws, **kw))
+        compiled = fn.lower(spikes, ws).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    out = jax.eval_shape(fn, spikes, ws, *((vs, ss) if carried else ()))
+    assert [tuple(r.shape) for r in out[3]] == [(BATCH, 400), (BATCH, 400)]
