@@ -1242,15 +1242,20 @@ def stream_megastep(program: SNNProgram, state: StreamState,
     use_events = backend in ("ref_events", "pallas_events")
     if backend == "pallas_sparse":
         use_sparse = True
-    # eager K-loop, not lax.scan: an un-jitted scan retraces per call,
-    # which would put a compile on every serving dispatch; the eager ops
-    # are exactly what `stream_step`/`encode` execute (bit-identical — the
-    # encoder comparison in tests/test_stream.py pins eager == scanned)
-    v_enc, spk_enc = state.vs[0], []
-    for t in range(k):
-        v_enc, s = encoder_step(program, v_enc, frames[t])
-        spk_enc.append(s)
-    spikes_enc = jnp.stack(spk_enc)
+    # the encoder: under a trace (the serving engine's jitted core) a
+    # lax.scan, as `encode` runs it, so the compiled program's size does
+    # not grow with K; eagerly a K-loop of the same step, since an
+    # un-jitted scan retraces on every call (bit-identical either way:
+    # tests/test_stream.py pins eager == scanned)
+    if isinstance(frames, jax.core.Tracer):
+        v_enc, spikes_enc = jax.lax.scan(
+            lambda v, xt: encoder_step(program, v, xt), state.vs[0], frames)
+    else:
+        v_enc, spk_enc = state.vs[0], []
+        for t in range(k):
+            v_enc, s = encoder_step(program, v_enc, frames[t])
+            spk_enc.append(s)
+        spikes_enc = jnp.stack(spk_enc)
     n_convs = len(program.int_conv_stack)
     conv_maps, v_convs, conv_skips = _conv_front_end(
         program, spikes_enc, use_pallas=use_pallas, use_sparse=use_sparse,

@@ -14,7 +14,8 @@ throughput (frames/s and words/s), the skipped-work fraction from the
 pooled per-slot event accounting, and the measured-EDP figure it implies.
 
 ``--stop-threshold`` enables the readout-confidence early exit;
-``--megastep K`` advances every lane K frames per device dispatch,
+the engine picks the frames each lane advances per device dispatch
+from its K ladder; ``--megastep K`` pins them to K,
 ``--pages N`` grows the V-slot pool to N pages of ``--slots`` lanes,
 ``--double-buffer`` stages the next frame block while one computes, and
 ``--poisson-gap G`` draws seeded Poisson arrivals (mean gap G frame
@@ -89,8 +90,9 @@ def main(argv=None):
                     choices=list(pipeline.STREAM_BACKENDS))
     ap.add_argument("--stop-threshold", type=float, default=None)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--megastep", type=int, default=1,
-                    help="frames advanced per device dispatch (K)")
+    ap.add_argument("--megastep", type=int, default=None,
+                    help="pin the frames advanced per device dispatch (K); "
+                         "default: the engine picks K per tick")
     ap.add_argument("--pages", type=int, default=1,
                     help="V-slot pool pages of --slots lanes each")
     ap.add_argument("--double-buffer", action="store_true",
@@ -151,7 +153,9 @@ def main(argv=None):
           f"{frames / cfg.timesteps / dt:.1f} words/s on {dev.platform} "
           f"{dev.device_kind}"
           f"{' (interpret)' if args.interpret else ''}; "
-          f"K={args.megastep}, {args.pages} page(s) x {args.slots} lanes"
+          f"K ladder {eng.rungs}, {eng.frames_dispatched} frames "
+          f"dispatched in {eng.ticks} ticks, "
+          f"{args.pages} page(s) x {args.slots} lanes"
           + (f", mesh data={args.mesh.split(',')[0]} "
              f"model={args.mesh.split(',')[1]}" if args.mesh else "") + ")")
     lats = [r.latency_ticks for r in done if r.latency_ticks is not None]

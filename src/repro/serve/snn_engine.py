@@ -13,10 +13,13 @@ engine mirrors `ServeEngine`:
     determined batch axis — the same B-vs-B+1 probe the LM engine uses on
     its cache tree), and each engine tick dispatches only the occupied
     pages;
-  * K-frame megasteps: every dispatch advances a page ``megastep`` frames
-    via `pipeline.stream_megastep` — the next K frames of each lane's
-    stream are pre-staged into one (K, B, *in_shape) host block, lanes
-    whose stream runs out inside the block integrate zero current
+  * K-frame megasteps: every dispatch advances a page K frames via
+    `pipeline.stream_megastep`, K picked per tick and page from a short
+    ladder of block lengths compiled before serving (the smallest that
+    covers the page's longest remaining budget), unless ``megastep`` pins
+    it. The next K frames of each lane's stream are pre-staged into one
+    (K, B, *in_shape) host block, lanes whose stream runs out inside the
+    block integrate zero current
     (active-mask contract), and requests that finish mid-block are
     finalized from the block's exact per-tick readout trajectory. Batch
     lanes never interact (every op is per-lane), so each request's output
@@ -24,13 +27,14 @@ engine mirrors `ServeEngine`:
   * double-buffered upload (``double_buffer=True``): after dispatching
     tick t's block, tick t+1's block is staged host-side and shipped with
     `jax.device_put` while the device computes; the staged block is keyed
-    by per-lane (request, cursor) metadata and rebuilt on any mismatch
-    (early exit, admission, eviction), so speculation never changes
+    by K and per-lane (request, cursor) metadata and rebuilt on any
+    mismatch (early exit, admission, eviction), so speculation never changes
     results;
   * admission control: requests carry an ``arrival_tick`` on the engine's
     frame clock (``clock`` advances K per engine tick, idle ticks
-    included) and are not admitted before it — a seeded Poisson arrival
-    process is just a sorted submission with exponential gaps;
+    included, and a block never carries it past a queued head's arrival)
+    and are not admitted before it — a seeded Poisson arrival process is
+    just a sorted submission with exponential gaps;
   * per-slot stop conditions: fixed tick budget (the frame sequence runs
     out) or readout-threshold early exit (|logit| confidence);
   * per-slot event accounting: input events per macro-stack layer row are
@@ -58,8 +62,8 @@ which is why per-request accounting stays raster-based.
 
 Every tick is annotated for `jax.profiler`: one ``snn_engine.step`` step
 span per `step` call, and phase spans inside it (``admit``, ``stage``,
-``dispatch``, ``stage_next``, ``retire`` with its ``fetch``, ``account``
-and ``evict`` children; docs/serving.md "Tracing"). With no trace being
+``dispatch`` with its K, ``stage_next``, ``retire`` with its ``fetch``,
+``account`` and ``evict`` children; docs/serving.md "Tracing"). With no trace being
 recorded a span costs about a microsecond and changes no output.
 """
 from __future__ import annotations
@@ -178,7 +182,17 @@ def merge_reports(reports: list) -> SparsityReport:
             for i in range(len(head.n_in))))
 
 
-_MEGASTEP_JIT = {}  # (id(program), backend, kw, rasters, mesh) -> (ref, fn)
+# (id(program), backend, kw, rasters, mesh) -> (program ref, jitted core,
+# {(K, lanes): compiled executable})
+_MEGASTEP_JIT = {}
+# the K ladder's ratio: rungs 1, 8, 64, ..., so below the top rung a page
+# runs fewer than 8x the frames its longest lane needs, at one compile per
+# rung
+_RUNG_STEP = 8
+# backends whose dispatch runs eagerly (no jitted core): the event-list
+# executors fold their ledgers to host numpy inside the op wrapper, and the
+# float backend's QAT ops stay eager for bit-identity with stream_step
+_EAGER_BACKENDS = ("float", "ref_events", "pallas_events")
 
 
 def _pack_lanes(out: pipeline.MegastepOut) -> jax.Array:
@@ -234,11 +248,18 @@ def _jit_megastep(program, backend, step_kw, emit_rasters, mesh=None):
     block's final readout V and logits, which the engine never reads, are
     no outputs at all.
     """
+    return _megastep_entry(program, backend, step_kw, emit_rasters,
+                           mesh)[0]
+
+
+def _megastep_entry(program, backend, step_kw, emit_rasters, mesh) -> tuple:
+    """The cache entry behind `_jit_megastep`: the jitted core and its
+    compiled executables, {(K, lanes): executable}."""
     key = (id(program), backend, tuple(sorted(step_kw.items())),
            emit_rasters, mesh)
     hit = _MEGASTEP_JIT.get(key)
     if hit is not None and hit[0]() is program:
-        return hit[1]
+        return hit[1:]
 
     def _core(st, block, counts):
         st2, out = pipeline.stream_megastep(
@@ -246,9 +267,59 @@ def _jit_megastep(program, backend, step_kw, emit_rasters, mesh=None):
             emit_rasters=emit_rasters, mesh=mesh, **step_kw)
         return st2, _pack_lanes(out), out.rasters, out.skips, out.conv_skips
 
-    fn = jax.jit(_core)
-    _MEGASTEP_JIT[key] = (weakref.ref(program), fn)
-    return fn
+    fn, exes = jax.jit(_core), {}
+    _MEGASTEP_JIT[key] = (weakref.ref(program), fn, exes)
+    return fn, exes
+
+
+class _Rungs:
+    """One compiled executable of the jitted megastep core per rung of
+    the engine's K ladder, compiled ahead of serving: a dispatch calls the
+    executable of its block's length, so no dispatch traces or compiles."""
+
+    def __init__(self, program, backend, step_kw, emit_rasters, mesh,
+                 state, lanes, frame_shape, rungs):
+        fn, exes = _megastep_entry(program, backend, step_kw, emit_rasters,
+                                   mesh)
+        for k in rungs:
+            if (k, lanes) not in exes:
+                exes[(k, lanes)] = fn.lower(
+                    state,
+                    jax.ShapeDtypeStruct((k, lanes, *frame_shape),
+                                         jnp.float32),
+                    jax.ShapeDtypeStruct((lanes,), jnp.int32)).compile()
+        self.exes = {k: exes[(k, lanes)] for k in rungs}
+
+    def __call__(self, st, block, counts):
+        return self.exes[block.shape[0]](st, block, counts)
+
+    def upload(self, block: np.ndarray, counts: np.ndarray) -> tuple:
+        """Put a host block and its active counts where the rung's
+        executable takes them (the data shards of a mesh engine)."""
+        _, at_block, at_counts = self.exes[block.shape[0]].input_shardings[0]
+        return (jax.device_put(block, at_block),
+                jax.device_put(counts, at_counts))
+
+
+def _ladder(contract, horizon: Optional[int]) -> tuple:
+    """The rungs of the K ladder: 1, then powers of `_RUNG_STEP` while
+    ``contract(K)`` (a Pallas kernel's contracts at K frames per dispatch,
+    whose VMEM estimate grows with K until the budget refuses it) passes
+    and K stays within the readout's proven ``horizon`` and the int32
+    frame counts."""
+    from repro.analysis import ContractError
+    rungs = [1]
+    while True:
+        k = rungs[-1] * _RUNG_STEP
+        if k > np.iinfo(np.int32).max or (horizon is not None
+                                            and k > horizon):
+            break
+        try:
+            contract(k)
+        except ContractError:
+            break
+        rungs.append(k)
+    return tuple(rungs)
 
 
 class SNNServeEngine(SlotEngine):
@@ -260,20 +331,32 @@ class SNNServeEngine(SlotEngine):
     emission and per-slot accounting — the pure-serving configuration in
     which inter-layer spikes never leave the kernel.
 
-    ``pages`` × ``batch_slots`` is the lane pool; ``megastep`` is K, the
-    frames advanced per dispatch; ``double_buffer`` stages the next block
-    while the current one computes. The defaults (1 page, K=1) reproduce
-    the classic tick-by-tick engine exactly.
+    ``pages`` × ``batch_slots`` is the lane pool; ``double_buffer`` stages
+    the next block while the current one computes. K, the frames a page
+    advances per dispatch, is the engine's own pick by default
+    (``megastep=None``): each tick every dispatched page takes the
+    smallest rung of the K ladder (`rungs`) that covers the longest
+    remaining budget among its lanes, capped at the top rung, and never
+    carries the frame clock past a queued head's future ``arrival_tick``.
+    The ladder is 1, 8, 64, ... up to the largest K that the VMEM contract
+    of this engine's Pallas kernel and the readout's proven horizon admit
+    (a backend with no VMEM contract of its own, int_ref, takes the ladder
+    of the `pallas` kernel it is the reference for), and every rung is
+    compiled before the engine serves.
+    The eager backends (float, ref_events, pallas_events), and engines
+    built with ``validate=False`` (no analysis to size the ladder from),
+    dispatch K=1. An explicit ``megastep`` pins K; ``megastep=1`` (one
+    page) reproduces the classic tick-by-tick engine exactly.
 
     ``validate`` (default on) runs the static analyzer at engine build
     time: the kernel contracts of this exact (backend, K, step_kw)
     dispatch are verified before the first tick — the VMEM budget scales
     with K — and the program's `RangeReport` caps admission: a request
-    whose tick budget, rounded up to the K-block horizon it will actually
-    execute, exceeds the readout's proven ``max_safe_frames`` (the horizon
-    past which the unclamped int32 accumulator can overflow) is rejected
-    at `submit` with a named `RangeError` instead of silently serving
-    garbage logits.
+    whose tick budget, plus the ghost frames the lane may integrate after
+    it (up to the top rung, less one), exceeds the readout's proven
+    ``max_safe_frames`` (the horizon past which the unclamped int32
+    accumulator can overflow) is rejected at `submit` with a named
+    `RangeError` instead of silently serving garbage logits.
 
     ``mesh`` (a `jax.sharding.Mesh` with "data"/"model" axes) partitions
     the paged V-slot pool: each page's state tree is placed with its lane
@@ -286,11 +369,11 @@ class SNNServeEngine(SlotEngine):
     def __init__(self, program: SNNProgram, *, batch_slots: int = 4,
                  backend: str = "int_ref", track_events: bool = True,
                  step_kw: Optional[dict] = None, validate: bool = True,
-                 pages: int = 1, megastep: int = 1,
+                 pages: int = 1, megastep: Optional[int] = None,
                  double_buffer: bool = False, mesh=None):
         if pages < 1:
             raise ValueError(f"pages must be >= 1, got {pages}")
-        if megastep < 1:
+        if megastep is not None and megastep < 1:
             raise ValueError(f"megastep must be >= 1, got {megastep}")
         if mesh is not None and backend == "float":
             raise ValueError(
@@ -302,23 +385,31 @@ class SNNServeEngine(SlotEngine):
         self.mesh = mesh
         self.B = batch_slots                  # lanes per page
         self.pages = pages
-        self.K = megastep
         self.double_buffer = double_buffer
         self.track_events = track_events
         self.step_kw = dict(step_kw or {})
         self.max_safe_ticks: Optional[int] = None
+        # frames per dispatch this engine may pick from, smallest first
+        self.rungs: tuple = (megastep or 1,)
         if validate:
             from repro.analysis import check_kernel_contracts, check_program
-            check_kernel_contracts(
-                program, backend, frames=megastep, streaming=True,
-                emit_rasters=track_events,
-                block_b=self.step_kw.get("block_b", 8),
-                gate_granularity=self.step_kw.get("gate_granularity", 1),
-                event_crossover=self.step_kw.get("event_crossover", 1.0),
-                use_sparse=self.step_kw.get("use_sparse", False),
-                mesh=mesh)
+
+            def contract(k, backend=backend):
+                return check_kernel_contracts(
+                    program, backend, frames=k, streaming=True,
+                    emit_rasters=track_events,
+                    block_b=self.step_kw.get("block_b", 8),
+                    gate_granularity=self.step_kw.get("gate_granularity", 1),
+                    event_crossover=self.step_kw.get("event_crossover", 1.0),
+                    use_sparse=self.step_kw.get("use_sparse", False),
+                    mesh=mesh)
+            contract(self.rungs[0])
             self.max_safe_ticks = check_program(
                 program, frames=1).max_safe_frames
+            if megastep is None and backend not in _EAGER_BACKENDS:
+                sizing = backend if backend.startswith("pallas") else "pallas"
+                self.rungs = _ladder(lambda k: contract(k, sizing),
+                                     self.max_safe_ticks)
         self.states = [pipeline.init_stream_state(program, batch_slots,
                                                   backend)
                        for _ in range(pages)]
@@ -357,22 +448,26 @@ class SNNServeEngine(SlotEngine):
         self.ticks = 0                    # engine ticks executed (dispatches)
         self.steps = 0                    # step() calls, idle ones included
         self.clock = 0                    # frame clock: K per engine tick
-        # jit the per-page megastep dispatch (the LM engine jits its
-        # decode_step the same way): block/counts shapes are fixed per
-        # engine config, so this compiles once. The event-list executors
-        # fold their ledgers to host numpy inside the op wrapper and the
-        # float backend's QAT ops are kept eager for bit-identity with
-        # stream_step — those take the direct path. The compiled core is
-        # cached per (program, backend, step_kw) so every engine over the
-        # same program — the warmup drain in the benchmark, a restarted
-        # server — shares one compile instead of retracing a fresh
-        # closure.
-        self._dispatch = None
-        if backend not in ("float", "ref_events", "pallas_events"):
-            self._dispatch = _jit_megastep(program, backend, self.step_kw,
-                                           track_events, mesh)
+        self.frames_dispatched = 0        # K summed over page dispatches
+        # the per-page megastep dispatch is jitted (the LM engine jits its
+        # decode_step the same way) and compiled here for every rung, so
+        # that serving never compiles. The compiled core is cached per
+        # (program, backend, step_kw): every engine over the same program
+        # — the warmup drain in the benchmark, a restarted server — shares
+        # its compiles. The eager backends take the direct path.
+        self._rungs = self._dispatch = None
+        if backend not in _EAGER_BACKENDS:
+            # admission's lane reset compiles on first use too: run it
+            # once per page here (a no-op on the zero pool), not while
+            # serving
+            self.states = [lane_scatter(self._fresh, st, self._batch_axes, 0)
+                           for st in self.states]
+            self._rungs = self._dispatch = _Rungs(
+                program, backend, self.step_kw, track_events, mesh,
+                self.states[0], batch_slots, self._frame_shape, self.rungs)
         self._admit_seq = 0
         self._staged: dict = {}           # page -> (meta, device block, counts)
+        self._host_blocks: dict = {}      # (page, K) -> [(host block, upload)]
         # pooled device-side event ledger (event backends only): per-layer
         # row-event counters as the executing kernel reports them
         self._event_backend = backend in ("ref_events", "pallas_events")
@@ -402,15 +497,19 @@ class SNNServeEngine(SlotEngine):
                 f"request {req.rid}: frame shape {req.frames.shape[1:]} "
                 f"does not match the program input {self._frame_shape}")
         budget = self._tick_budget(req)
-        # the lane executes whole K-blocks: a request finishing mid-block
+        # the lane executes whole blocks: a request finishing mid-block
         # still integrates (masked, zero-current) ticks to the block edge,
-        # so the proven-safe horizon must cover the K-rounded budget
-        horizon = -(-budget // self.K) * self.K
+        # so the proven-safe horizon must cover them: the K-rounded budget
+        # at a pinned K, else up to a top-rung block less the one frame
+        # served in it (a block cut short by an arrival is not top-sized)
+        top = self.rungs[-1]
+        horizon = (-(-budget // top) * top if len(self.rungs) == 1
+                   else budget + top - 1)
         if self.max_safe_ticks is not None and horizon > self.max_safe_ticks:
             from repro.analysis import RangeError
             raise RangeError(
                 f"request {req.rid} streams {budget} ticks "
-                f"({horizon} at megastep K={self.K}) but the readout's "
+                f"({horizon} with blocks of up to K={top}) but the readout's "
                 f"unclamped int32 accumulator is only proven safe for "
                 f"{self.max_safe_ticks} frames; split the stream or cap "
                 "max_ticks", where="readout")
@@ -511,12 +610,12 @@ class SNNServeEngine(SlotEngine):
                 slot.rec_events[li] += slot.rec_last[li] + int(ticks[:-1].sum())
                 slot.rec_last[li] = int(ticks[-1])
 
-    def _account_device(self, out) -> None:
+    def _account_device(self, out, k: int) -> None:
         """Pool one dispatch's executor-reported `EventStats` (fc stack in
         ``out.skips``, one per conv layer in ``out.conv_skips``) into the
         engine-lifetime device ledger. These are the counters the event
         executor measured while running — for `pallas_events`, on device —
-        over ALL lanes of the dispatched page, K frames each (module
+        over ALL lanes of the dispatched page, ``k`` frames each (module
         docs)."""
         rows = [np.asarray(r, np.int64)
                 for st in (out.conv_skips or []) for r in st.row_events]
@@ -533,7 +632,7 @@ class SNNServeEngine(SlotEngine):
             if fbs:
                 self.device_dense_fallbacks = [
                     a + b for a, b in zip(self.device_dense_fallbacks, fbs)]
-        self.device_ticks += self.K
+        self.device_ticks += k
 
     def device_event_stats(self):
         """The pooled device ledger as an `events.EventStats`: per-layer
@@ -585,71 +684,109 @@ class SNNServeEngine(SlotEngine):
                         else tuple(slot.rec_events)))
 
     # -- frame staging -------------------------------------------------------
-    def _block_meta(self, page: int) -> tuple:
-        """Identity of the block a page would dispatch right now: per
-        occupied lane (admission serial, cursor, staged tick count). The
-        key that validates a speculatively staged block."""
-        meta = []
+    def _lanes(self, page: int, ahead: int = 0):
+        """(slot index, request, cursor, budget) of each lane of ``page``
+        still streaming after ``ahead`` more frames (0: as it stands)."""
         for i in self.page_lanes(page):
-            slot = self.slots[i]
-            if slot.req is None:
+            req = self.slots[i].req
+            if req is None:
                 continue
-            n = min(self._tick_budget(slot.req) - slot.cursor, self.K)
-            meta.append((slot.serial, slot.cursor, n))
-        return tuple(meta)
+            budget = self._tick_budget(req)
+            cursor = min(self.slots[i].cursor + ahead, budget)
+            if cursor < budget:
+                yield i, req, cursor, budget
 
-    def _build_block(self, page: int, at_next: bool = False):
+    def _rung(self, need: int, clock: int) -> int:
+        """Frames for a dispatch at frame clock ``clock`` whose longest lane
+        has ``need`` frames left: the smallest rung covering it (the top
+        rung when none does), cut to the largest rung that does not carry
+        the clock past the queue head's future arrival."""
+        k = next((r for r in self.rungs if r >= need), self.rungs[-1])
+        if not self.queue.empty():
+            gap = self.queue.peek().arrival_tick - clock
+            if gap > 0:
+                k = min(k, max((r for r in self.rungs if r <= gap),
+                               default=self.rungs[0]))
+        return k
+
+    def _page_rung(self, page: int, ahead: int = 0,
+                   clock: Optional[int] = None) -> Optional[int]:
+        """The rung ``page`` dispatches at ``clock`` (default: now) once
+        its lanes have advanced ``ahead`` frames; None when no lane would
+        be left streaming."""
+        need = max((b - c for *_, c, b in self._lanes(page, ahead)),
+                   default=0)
+        if not need:
+            return None
+        return self._rung(need, self.clock if clock is None else clock)
+
+    def _block_meta(self, page: int, k: int) -> tuple:
+        """Identity of the K-frame block a page would dispatch right now:
+        K, then per occupied lane (admission serial, cursor, staged tick
+        count). The key that validates a speculatively staged block."""
+        return (k,) + tuple((self.slots[i].serial, c, min(b - c, k))
+                            for i, _, c, b in self._lanes(page))
+
+    def _build_block(self, page: int, k: int, ahead: int = 0):
         """Assemble one page's (K, B, *in_shape) frame block and per-lane
-        active counts — from each lane's current cursor, or (``at_next``)
-        from its predicted post-dispatch cursor for double-buffer
-        speculation. Returns (meta, host block, counts); meta/block are
-        None when no lane would be active."""
-        block = np.zeros((self.K, self.B, *self._frame_shape), np.float32)
+        active counts from each lane's cursor, advanced ``ahead`` frames
+        for double-buffer speculation, and upload them. Returns (meta,
+        device block, counts).
+
+        The host block is one of the page's buffers for this K, reused
+        tick after tick: only the live lanes' frames are written, since
+        `stream_megastep` zeroes every frame past a lane's active count,
+        and a written buffer uploads several times faster than fresh zero
+        pages. The buffers take turns (two with ``double_buffer``, so the
+        block staged ahead never overwrites the one being dispatched); a
+        buffer is rewritten only after its last upload has landed and the
+        dispatch that read it has been retired (on a CPU the device array
+        may alias the host buffer)."""
+        ring = self._host_blocks.setdefault((page, k), [])
+        if len(ring) <= self.double_buffer:
+            block = np.zeros((k, self.B, *self._frame_shape), np.float32)
+        else:
+            block, landed = ring.pop(0)
+            landed.block_until_ready()
         counts = np.zeros(self.B, np.int32)
-        meta, any_live = [], False
-        for i in self.page_lanes(page):
-            slot = self.slots[i]
-            if slot.req is None:
-                continue
-            budget = self._tick_budget(slot.req)
-            cursor = slot.cursor
-            if at_next:
-                cursor += min(budget - cursor, self.K)
-                if cursor >= budget:
-                    continue              # predicted finished by then
-            n = min(budget - cursor, self.K)
-            block[:n, i % self.B] = slot.req.frames[cursor:cursor + n]
+        meta = [k]
+        for i, req, cursor, budget in self._lanes(page, ahead):
+            n = min(budget - cursor, k)
+            block[:n, i % self.B] = req.frames[cursor:cursor + n]
             counts[i % self.B] = n
-            meta.append((slot.serial, cursor, n))
-            any_live = True
-        if not any_live:
-            return None, None, None
-        return tuple(meta), block, counts
+            meta.append((self.slots[i].serial, cursor, n))
+        if self._rungs is not None:
+            on_device = self._rungs.upload(block, counts)
+        else:
+            on_device = jax.device_put(block), counts
+        ring.append((block, on_device[0]))
+        return (tuple(meta), *on_device)
 
-    def _stage_block(self, page: int):
-        """The block a page dispatches this tick: the double-buffered
-        upload when its metadata still matches (no early exit, admission,
-        or eviction invalidated the speculation), else built fresh."""
+    def _stage_block(self, page: int, k: int):
+        """The K-frame block a page dispatches this tick: the
+        double-buffered upload when its metadata still matches (no early
+        exit, admission, eviction or other K invalidated the speculation),
+        else built fresh."""
         staged = self._staged.pop(page, None)
-        if staged is not None and staged[0] == self._block_meta(page):
+        if staged is not None and staged[0] == self._block_meta(page, k):
             return staged[1], staged[2]
-        meta, block, counts = self._build_block(page)
-        return jnp.asarray(block), counts
+        return self._build_block(page, k)[1:]
 
-    def _stage_next(self, pages: list) -> None:
+    def _stage_next(self, ks: dict, clock: int) -> None:
         """Double buffer: stage tick t+1's blocks (host assembly + device
-        upload) while tick t's dispatches compute. Pure speculation —
+        upload) while tick t's dispatches compute, each page ``ks[page]``
+        frames on and at frame clock ``clock``. Pure speculation —
         `_stage_block` re-validates against live metadata, so a mismatch
         costs one rebuild and never changes results."""
-        for page in pages:
-            meta, block, counts = self._build_block(page, at_next=True)
-            if meta is not None:
-                self._staged[page] = (meta, jax.device_put(block), counts)
+        for page, k in ks.items():
+            k_next = self._page_rung(page, ahead=k, clock=clock)
+            if k_next is not None:
+                self._staged[page] = self._build_block(page, k_next, k)
 
     # -- engine tick ---------------------------------------------------------
     def step(self) -> int:
-        """One engine tick: admit, then one K-frame megastep per occupied
-        page. Returns #active slots remaining after evictions."""
+        """One engine tick: admit, then one megastep per occupied page.
+        Returns #active slots remaining after evictions."""
         with StepTraceAnnotation("snn_engine.step", step_num=self.steps):
             self.steps += 1
             return self._tick()
@@ -662,13 +799,15 @@ class SNNServeEngine(SlotEngine):
                 # only future arrivals remain: idle ticks still advance
                 # the frame clock so Poisson schedules reach their
                 # arrival times under run_until_drained
-                self.clock += self.K
+                self.clock += self._rung(
+                    self.queue.peek().arrival_tick - self.clock, self.clock)
             return 0
-        outs = {}
+        outs, ks = {}, {}
         for page in sorted(by_page):
+            k = ks[page] = self._page_rung(page)
             with TraceAnnotation("snn_engine.stage", page=page):
-                block, counts = self._stage_block(page)
-            with TraceAnnotation("snn_engine.dispatch", page=page):
+                block, counts = self._stage_block(page, k)
+            with TraceAnnotation("snn_engine.dispatch", page=page, k=k):
                 if self._dispatch is not None:
                     self.states[page], *rest = self._dispatch(
                         self.states[page], block, counts)
@@ -679,17 +818,22 @@ class SNNServeEngine(SlotEngine):
                         self.backend, active=counts,
                         emit_rasters=self.track_events, mesh=self.mesh,
                         **self.step_kw)
+            self.frames_dispatched += k
+        # a page dispatching fewer frames than the longest finished every
+        # lane inside its block (its K covered them all), so the clock
+        # advances by the longest block
+        start, self.clock = self.clock, self.clock + max(ks.values())
         if self.double_buffer:
             with TraceAnnotation("snn_engine.stage_next"):
-                self._stage_next(sorted(by_page))
+                self._stage_next(ks, self.clock)
         self.ticks += 1
-        self.clock += self.K
         for page in sorted(by_page):
             with TraceAnnotation("snn_engine.retire", page=page):
-                self._retire_page(page, by_page[page], outs[page])
+                self._retire_page(page, by_page[page], outs[page], ks[page],
+                                  start)
         return sum(1 for s in self.slots if s.req is not None)
 
-    def _fetch(self, out) -> tuple:
+    def _fetch(self, out, k: int) -> tuple:
         """Read one page's per-lane results to the host: (K, B, n_out)
         logit and readout V trajectories and (B,) frames consumed. The
         jitted path reads its packed array in one transfer; the eager
@@ -698,7 +842,7 @@ class SNNServeEngine(SlotEngine):
             with TraceAnnotation("snn_engine.fetch", reads=1):
                 host = np.asarray(out.packed)
             self.device_reads += 1
-            return _unpack_lanes(host, self.K, self._n_out[-1])
+            return _unpack_lanes(host, k, self._n_out[-1])
         with TraceAnnotation("snn_engine.fetch", reads=3):
             fetched = (np.asarray(out.logits_traj),
                        np.asarray(out.v_out_traj),
@@ -706,27 +850,30 @@ class SNNServeEngine(SlotEngine):
         self.device_reads += 3
         return fetched
 
-    def _retire_page(self, page: int, lanes: list, out) -> None:
-        """Account one page's megastep and finalize the requests that
-        finished inside it — from the block's per-tick readout trajectory,
-        at the exact tick a K=1 drain would have stopped on."""
-        logits, v_traj, consumed = self._fetch(out)
+    def _retire_page(self, page: int, lanes: list, out, k: int,
+                     start: int) -> None:
+        """Account one page's K-frame megastep, dispatched at frame clock
+        ``start``, and finalize the requests that finished inside it —
+        from the block's per-tick readout trajectory, at the exact tick a
+        K=1 drain would have stopped on."""
+        logits, v_traj, consumed = self._fetch(out, k)
         served, fins = [], []
         for i in lanes:
             slot = self.slots[i]
             req = slot.req
             lane = i % self.B
             n = int(consumed[lane])
-            fin = None
-            for t in range(n):
-                if (req.stop_threshold is not None
-                        and float(np.max(np.abs(logits[t, lane])))
-                        >= req.stop_threshold):
-                    fin = t                        # confident readout: stop
-                    break
-                if slot.cursor + t + 1 >= self._tick_budget(req):
-                    fin = t                        # budget exhausted
-                    break
+            # the first served tick that exhausts the budget or, earlier,
+            # reads a confident logit (compared in float64, as a Python
+            # float threshold is)
+            left = self._tick_budget(req) - slot.cursor
+            fin = left - 1 if left <= n else None
+            if req.stop_threshold is not None:
+                peak = np.abs(logits[:n, lane]).max(axis=-1)
+                hit = np.flatnonzero(peak.astype(np.float64)
+                                     >= req.stop_threshold)
+                if hit.size and (fin is None or hit[0] < fin):
+                    fin = int(hit[0])
             credit = n if fin is None else fin + 1
             served.append((i, lane, credit))
             slot.cursor += credit
@@ -740,7 +887,7 @@ class SNNServeEngine(SlotEngine):
                 if by_rasters:
                     self._account(out.rasters, served)
                 if by_device:
-                    self._account_device(out)
+                    self._account_device(out, k)
         for i, lane, fin in fins:
             slot = self.slots[i]
             req = slot.req
@@ -748,7 +895,7 @@ class SNNServeEngine(SlotEngine):
                 req.logits = logits[fin, lane].copy()
                 req.v_out = v_traj[fin, lane].copy()
                 req.ticks = slot.ticks
-                req.finish_clock = self.clock - self.K + fin + 1
+                req.finish_clock = start + fin + 1
                 if self.track_events:
                     req.report = self._finalize_report(slot)
                 self.finished.append(req)

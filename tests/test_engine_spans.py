@@ -143,6 +143,8 @@ def test_engine_spans(tmp_path, program, megastep, double_buffer,
     assert sorted(s[3]["page"] for s in spans
                   if s[0] == "snn_engine.dispatch") == sorted(
         s[3]["page"] for s in retires)
+    assert {s[3]["k"] for s in spans
+            if s[0] == "snn_engine.dispatch"} == {megastep}
 
     # per tick: the step, 4 per dispatched page (stage, dispatch, retire,
     # fetch), account per page when tracking, stage_next when double

@@ -277,7 +277,8 @@ def test_engine_megastep_drain_matches_k1(backend, kw, megastep, pages, db):
     logits, V, ticks, per-request reports, and the merged report."""
     cfg, program = _program()
     lengths = [2, 4, 1, 3, 2, 1]
-    ref_eng, ref = _drain(program, cfg, backend, kw, lengths, stop_rid=3)
+    ref_eng, ref = _drain(program, cfg, backend, kw, lengths, stop_rid=3,
+                          megastep=1)
     got_eng, got = _drain(program, cfg, backend, kw, lengths, stop_rid=3,
                           megastep=megastep, pages=pages, double_buffer=db)
     _assert_drains_equal(ref, got, f"{backend}/K={megastep}")

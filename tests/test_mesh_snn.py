@@ -267,6 +267,42 @@ def _drain(program, mesh, backend, kw, megastep=4, pages=2):
 @needs4
 @pytest.mark.parametrize("backend,kw",
                          [("int_ref", {}),
+                          ("pallas", {"interpret": True, "block_b": 4})],
+                         ids=["int_ref", "pallas"])
+def test_mesh_serving_ladder_drain(backend, kw):
+    """With the engine picking K, a mesh-partitioned pool (lanes over
+    data=2, rows over model=2) serves every request as the single-device
+    ``megastep=1`` engine does, and its blocks land on the data shards
+    the compiled rungs take."""
+    program, _ = _make()
+    a = _drain(program, None, backend, kw, megastep=1)
+    b = SNNServeEngine(program, batch_slots=4, backend=backend, step_kw=kw,
+                       pages=2, mesh=make_host_mesh(4, model=2))
+    compiles = []
+
+    def hear(name, _secs, **_kw):
+        if name in ("/jax/core/compile/backend_compile_duration",
+                    "/jax/core/compile/jaxpr_trace_duration"):
+            compiles.append(name)
+    jax.monitoring.register_event_duration_secs_listener(hear)
+    try:
+        for r in _requests():
+            b.submit(r)
+        b.run_until_drained()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(hear)
+    assert not compiles
+    assert b.frames_dispatched > b.ticks
+    for ra, rb in zip(sorted(a.finished, key=lambda r: r.rid),
+                      sorted(b.finished, key=lambda r: r.rid)):
+        assert ra.ticks == rb.ticks
+        np.testing.assert_array_equal(ra.logits, rb.logits)
+        np.testing.assert_array_equal(ra.v_out, rb.v_out)
+
+
+@needs4
+@pytest.mark.parametrize("backend,kw",
+                         [("int_ref", {}),
                           ("ref_events", {}),
                           ("pallas_events", {"interpret": True,
                                              "block_b": 4})],

@@ -290,6 +290,32 @@ def test_engine_drain_matches_reference(backend, sizes):
                for li in (0, 1))           # both W_rec blocks see events
 
 
+@pytest.mark.parametrize("backend", ["int_ref", "pallas"])
+def test_engine_ladder_drain_matches_k1(backend):
+    """16 → 16r → 16r → 3 with the engine picking K: the recurrent raster
+    rides each block, and every request's readout V, logits, ticks and
+    W_rec event counts equal the ``megastep=1`` drain's."""
+    net = make_net(3, EQUAL_WIDTHS)
+    program = pipeline.compile_network(_cfg(EQUAL_WIDTHS), net.params,
+                                       domain="int")
+    kw = {"interpret": True} if backend == "pallas" else {}
+
+    def drain(**ekw):
+        eng = SNNServeEngine(program, batch_slots=3, backend=backend,
+                             step_kw=kw, **ekw)
+        for _, r in _requests(net, n_in=EQUAL_WIDTHS[0]):
+            eng.submit(r)
+        return eng, {r.rid: r for r in eng.run_until_drained()}
+    _, want = drain(megastep=1)
+    eng, got = drain()
+    assert eng.frames_dispatched > eng.ticks          # blocks of K > 1
+    for rid, r in want.items():
+        assert got[rid].ticks == r.ticks
+        np.testing.assert_array_equal(got[rid].v_out, r.v_out)
+        np.testing.assert_array_equal(got[rid].logits, r.logits)
+        assert got[rid].report.rec_events == r.report.rec_events
+
+
 def test_evicted_lane_spike_state_resets(net, program):
     """A finished request leaves its lane with zero rasters (and V), so
     the next admission starts from s(-1) = 0."""

@@ -345,7 +345,8 @@ def test_snn_engine_undrained_raises():
     engine: EngineUndrained carries the partial finished list, and the
     engine can keep draining afterwards."""
     cfg, program = _imdb_like_program(seed=6)
-    eng = SNNServeEngine(program, batch_slots=1, backend="int_ref")
+    eng = SNNServeEngine(program, batch_slots=1, backend="int_ref",
+                         megastep=1)
     for rid in range(3):
         eng.submit(_word_request(cfg, rid, 2, seed=80 + rid)[0])
     with pytest.raises(EngineUndrained) as ei:
